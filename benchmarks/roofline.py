@@ -24,8 +24,11 @@ import glob
 import json
 import os
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import device_peaks
 from repro.models.common import INPUT_SHAPES
+
+# the chip these analytic tables are derived for (a jax ``device_kind``)
+TARGET_KIND = "TPU v5 lite"
 
 _BOTTLENECK_ADVICE = {
     "compute": "raise arithmetic efficiency: larger per-chip batch/seq tiles, "
@@ -48,6 +51,7 @@ def tokens_processed(rec: dict) -> int:
 
 
 def derive(rec: dict) -> dict:
+    peaks = device_peaks(TARGET_KIND)
     chips = rec["n_devices"]
     # prefer the loop-aware HLO accounting (cost_analysis counts lax.scan
     # bodies once -> ~n_layers too low; see repro.launch.hlo_analysis)
@@ -61,9 +65,9 @@ def derive(rec: dict) -> dict:
         bytes_chip = rec["cost_analysis"].get("bytes accessed", 0.0)
         coll_chip = rec["collective_bytes"].get("total", 0.0)
 
-    compute_t = flops_chip / PEAK_FLOPS_BF16
-    memory_t = bytes_chip / HBM_BW
-    coll_t = coll_chip / ICI_BW
+    compute_t = flops_chip / peaks["flops_bf16"]
+    memory_t = bytes_chip / peaks["hbm_bw"]
+    coll_t = coll_chip / peaks["ici_bw"]
     terms = {"compute": compute_t, "memory": memory_t, "collective": coll_t}
     dominant = max(terms, key=terms.get)
 
@@ -106,19 +110,21 @@ def gather_mix_rows(ms=(1024, 4096, 16384, 131072), d_max: int = 12,
     measured crossover (benchmarks/kernel_bench.py) sits orders of
     magnitude below the analytic memory crossover and the fleet engine
     switches on measured throughput, not this table."""
+    peak_flops, hbm_bw = (device_peaks(TARGET_KIND)[k]
+                          for k in ("flops_bf16", "hbm_bw"))
     out = []
     for m in ms:
         dense_flops = 2.0 * m * m * n
         dense_bytes = (m * m + 2.0 * m * n) * 4
         sparse_flops = 2.0 * m * (d_max + 1) * n
         sparse_bytes = ((d_max + 2.0) * m * n + 2.0 * m * d_max) * 4
-        dense_t = max(dense_flops / PEAK_FLOPS_BF16, dense_bytes / HBM_BW)
-        sparse_t = max(sparse_flops / PEAK_FLOPS_BF16, sparse_bytes / HBM_BW)
+        dense_t = max(dense_flops / peak_flops, dense_bytes / hbm_bw)
+        sparse_t = max(sparse_flops / peak_flops, sparse_bytes / hbm_bw)
         out.append({
             "m": m, "d_max": d_max, "n": n,
             "dense_s": dense_t, "sparse_s": sparse_t,
-            "dense_bound": ("compute" if dense_flops / PEAK_FLOPS_BF16
-                            >= dense_bytes / HBM_BW else "memory"),
+            "dense_bound": ("compute" if dense_flops / peak_flops
+                            >= dense_bytes / hbm_bw else "memory"),
             "winner": "sparse" if sparse_t < dense_t else "dense",
         })
     return out

@@ -65,6 +65,7 @@ def main():
 
     import numpy as np
 
+    from repro.compile_cache import use_compile_cache
     from repro.core.efhc import MIX_IMPLS
     from repro.core.topology import fleet_radius, make_process
     from repro.data.loader import FederatedBatches
@@ -76,6 +77,7 @@ def main():
 
     if args.mix_impl not in (*MIX_IMPLS, "sharded"):
         ap.error(f"unknown --mix-impl {args.mix_impl!r}")
+    use_compile_cache()
     if args.mix_impl == "sharded" and args.trace != "summary":
         ap.error("the sharded engine keeps only summary traces")
 

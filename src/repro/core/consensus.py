@@ -31,12 +31,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# consensus matmuls run at full f32: at default precision a TPU rounds the
+# operands to bf16, and P @ W came out 2.1e-3 off float64 (8.5e-8 at
+# HIGHEST) on a v5e at m=10, n=7850; P's row sums stayed exact at both
+# (chip_smoke.py's precision probe; the CPU ignores the setting)
+MIX_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def mix_dense(p: jax.Array, w_stack) -> jax.Array:
     """w_stack: pytree whose leaves have leading device axis m."""
     def mix_leaf(x):
         flat = x.reshape(x.shape[0], -1)
-        out = p.astype(flat.dtype) @ flat
+        out = jnp.matmul(p.astype(flat.dtype), flat, precision=MIX_PRECISION)
         return out.reshape(x.shape)
 
     return jax.tree.map(mix_leaf, w_stack)
@@ -47,7 +53,7 @@ def mix_delta_dense(p: jax.Array, w_stack):
     doubly stochastic P but numerically friendlier near P ~= I."""
     def mix_leaf(x):
         flat = x.reshape(x.shape[0], -1).astype(jnp.float32)
-        delta = p.astype(jnp.float32) @ flat - flat
+        delta = jnp.matmul(p.astype(jnp.float32), flat, precision=MIX_PRECISION) - flat
         return (flat + delta).reshape(x.shape).astype(x.dtype)
 
     return jax.tree.map(mix_leaf, w_stack)

@@ -388,7 +388,7 @@ def step(
             nb_cnt = adj_ell.sum(axis=1, dtype=jnp.float32)
         else:
             a_f = adj.astype(jnp.float32)
-            nb_sum = a_f @ w_flat
+            nb_sum = jnp.matmul(a_f, w_flat, precision=consensus.MIX_PRECISION)
             nb_cnt = a_f.sum(axis=1)
         nb_avg = nb_sum / jnp.maximum(nb_cnt, 1.0)[:, None]
         patch = jnp.logical_and(rejoined, nb_cnt > 0)

@@ -459,8 +459,11 @@ class ScenarioService:
     compile once, stream cells through.
 
     ``mix_impl="sharded"`` requests are accepted but execute their cells
-    serially (vmap over a shard_map program is unsupported on the pinned
-    jax); they still share one compiled engine via the simulator cache.
+    serially, as ``fl.sweep`` does for sharded grids (vmap over the
+    shard_map engine composes, ``tests/sharded_worker.py vmap``, but a
+    batched grid of fleets that need sharding would not fit); they still
+    share one compiled
+    engine via the simulator cache.
 
     Hardening (DESIGN.md "Fault injection & resilience"): a round that
     fails is retried up to ``max_retries`` times per request with

@@ -41,16 +41,6 @@ from repro.optim.schedules import paper_diminishing
 _AXIS = "fl"
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):  # jax >= 0.6: manual axes named directly
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as shmap
-
-    return shmap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 check_rep=False)
-
-
 def make_sharded_engine(
     sim,
     graph: GraphProcess,
@@ -213,7 +203,8 @@ def make_sharded_engine(
                  "window_connected": P(), "window_needed": P()}
     in_specs = ((P(), P(), P(), P(), P(), P(), P(), P(None, _AXIS, None))
                 + (P(_AXIS),) * len(tables))
-    mapped = _shard_map(shard_body, mesh, in_specs, out_specs)
+    mapped = jax.shard_map(shard_body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
 
     def engine(policy_idx, seed, idx):
         policy_idx = jnp.asarray(policy_idx, jnp.int32)

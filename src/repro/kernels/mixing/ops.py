@@ -1,23 +1,29 @@
-"""jit'd public wrapper: pads n to the block size, applies the kernel
+"""jit'd public wrapper: pads m and n to the block sizes, applies the kernel
 leaf-wise over a stacked parameter pytree."""
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import aligned_block
-from repro.kernels.mixing.kernel import mix_pallas, mix_sparse_pallas
+from repro.kernels import aligned_block, cover, pad_rows_cols, row_block
+from repro.kernels.mixing.kernel import (SMEM_TILE, mix_pallas,
+                                         mix_sparse_pallas)
 
 
 def mix(p: jax.Array, w: jax.Array, *, block_n: int = 512,
         interpret: bool = False) -> jax.Array:
-    """p (m, m); w (m, n) -> (m, n); pads n up to a block multiple."""
+    """p (m, m); w (m, n) -> (m, n); zero-pads m and n up to block
+    multiples (zero rows/columns of P and W add nothing)."""
     m, n = w.shape
+    block_m = row_block(m)
     block_n = aligned_block(n, block_n)
-    pad = (-n) % block_n
-    wp = jnp.pad(w, ((0, 0), (0, pad))) if pad else w
-    out = mix_pallas(p, wp, block_n=block_n, interpret=interpret)
-    return out[:, :n] if pad else out
+    rows, cols = cover(m, block_m), cover(n, block_n)
+    out = mix_pallas(pad_rows_cols(p.astype(jnp.float32), rows, rows),
+                     pad_rows_cols(w, rows, cols), block_m=block_m,
+                     block_n=block_n, interpret=interpret)
+    return out[:m, :n]
 
 
 def mix_tree(p: jax.Array, tree, *, block_n: int = 512, interpret: bool = False):
@@ -34,17 +40,27 @@ def mix_tree(p: jax.Array, tree, *, block_n: int = 512, interpret: bool = False)
 def mix_sparse(nbr_idx: jax.Array, p_diag: jax.Array, p_off: jax.Array,
                w: jax.Array, *, block_n: int = 256,
                interpret: bool = False) -> jax.Array:
-    """ELL gather-mix: nbr_idx/p_off (m, d_max), p_diag (m,), w (m, n);
-    pads n up to a block multiple."""
+    """ELL gather-mix: nbr_idx/p_off (m, d_max), p_diag (m,), w (m, n).
+    Builds the kernel's flat slot tables (slot 0 = the row itself with
+    p_ii) and zero-pads rows, slots and columns up to block multiples:
+    padded slots and rows carry zero weight and gather row 0, so they are
+    inert."""
     m, n = w.shape
+    block_m = row_block(m)
     block_n = aligned_block(n, block_n)
-    pad = (-n) % block_n
-    wp = jnp.pad(w, ((0, 0), (0, pad))) if pad else w
-    out = mix_sparse_pallas(nbr_idx.astype(jnp.int32),
-                            p_diag.astype(jnp.float32).reshape(m, 1),
-                            p_off.astype(jnp.float32), wp,
+    rows, cols = cover(m, block_m), cover(n, block_n)
+    slots = nbr_idx.shape[1] + 1
+    if block_m < rows:  # a row block's slots must fill whole SMEM tiles
+        slots = cover(slots, SMEM_TILE // math.gcd(block_m, SMEM_TILE))
+    self_idx = jnp.arange(m, dtype=jnp.int32)[:, None]
+    idx = jnp.concatenate([self_idx, nbr_idx.astype(jnp.int32)], axis=1)
+    p = jnp.concatenate([p_diag.astype(jnp.float32).reshape(m, 1),
+                         p_off.astype(jnp.float32)], axis=1)
+    out = mix_sparse_pallas(pad_rows_cols(idx, rows, slots).reshape(-1),
+                            pad_rows_cols(p, rows, slots).reshape(-1),
+                            pad_rows_cols(w, rows, cols), block_m=block_m,
                             block_n=block_n, interpret=interpret)
-    return out[:, :n] if pad else out
+    return out[:m, :n]
 
 
 def mix_sparse_tree(nbr_idx: jax.Array, p_diag: jax.Array, p_off: jax.Array,

@@ -6,16 +6,15 @@ import jax.numpy as jnp
 
 from repro.kernels.swa.kernel import swa_attention_pallas
 
-# interpret=True everywhere on this CPU container; flipped to False on TPU.
-_INTERPRET = jax.default_backend() == "cpu"
-
 
 def swa_attention(q, k, v, *, window: int, causal: bool = True,
                   block_q: int = 128, block_k: int = 128,
                   interpret: bool | None = None):
-    """q (B,S,H,dh), k/v (B,S,G,dh) -> (B,S,H,dh)."""
+    """q (B,S,H,dh), k/v (B,S,G,dh) -> (B,S,H,dh).  ``interpret=None``
+    compiles the kernel on a TPU and interprets it anywhere else."""
     assert causal, "SWA kernel is causal-only"
-    interp = _INTERPRET if interpret is None else interpret
+    interp = (jax.default_backend() != "tpu" if interpret is None
+              else interpret)
     s = q.shape[1]
     bq = min(block_q, s)
     bk = min(block_k, s, window)

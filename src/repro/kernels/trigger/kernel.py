@@ -4,11 +4,13 @@ Computes per-FL-device squared parameter deviation
 
     sq[i] = sum_n (w[i, n] - w_hat[i, n])^2
 
-without materializing (w - w_hat) in HBM.  W is streamed through VMEM in
-(m x bn) tiles; a (m x 128) f32 accumulator output block is revisited by
-every grid step (TPU grids execute sequentially, so read-modify-write on a
-revisited output block is well-defined).  Lane reduction to (m,) happens in
-the ops wrapper.
+without materializing (w - w_hat) in HBM.  Grid (m // bm, n // bn): row
+blocks are independent ("parallel"); column blocks stream (bm x bn) tiles
+of w and w_hat through VMEM and fold them into a (bm x 128) f32 output
+block that every column step of the row revisits ("arbitrary": the column
+steps of one row block run in order, so the read-modify-write on the
+revisited block is well-defined).  Lane reduction to (m,) happens in the
+ops wrapper.
 """
 from __future__ import annotations
 
@@ -17,39 +19,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 
 
 def _trigger_kernel(w_ref, h_ref, o_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
     d = w_ref[...].astype(jnp.float32) - h_ref[...].astype(jnp.float32)
-    sq = d * d  # (m, bn)
-    m, bn = sq.shape
-    part = sq.reshape(m, bn // LANES, LANES).sum(axis=1)  # (m, LANES)
-    o_ref[...] += part
+    sq = d * d  # (bm, bn)
+    bm, bn = sq.shape
+    o_ref[...] += sq.reshape(bm, bn // LANES, LANES).sum(axis=1)  # (bm, LANES)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def trigger_sq_pallas(w: jax.Array, w_hat: jax.Array, *, block_n: int = 1024,
-                      interpret: bool = False) -> jax.Array:
-    """w, w_hat (m, n); n % block_n == 0; returns (m, 128) partial sums."""
+@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "interpret"))
+def trigger_sq_pallas(w: jax.Array, w_hat: jax.Array, *, block_m: int = 256,
+                      block_n: int = 1024, interpret: bool = False) -> jax.Array:
+    """w, w_hat (m, n); m % block_m == 0 and n % block_n == 0 (the ops
+    wrapper pads); returns (m, 128) partial sums."""
     m, n = w.shape
-    assert n % block_n == 0 and block_n % LANES == 0
-    grid = (n // block_n,)
+    assert m % block_m == 0 and n % block_n == 0 and block_n % LANES == 0
     return pl.pallas_call(
         _trigger_kernel,
-        grid=grid,
+        grid=(m // block_m, n // block_n),
         in_specs=[
-            pl.BlockSpec((m, block_n), lambda i: (0, i)),
-            pl.BlockSpec((m, block_n), lambda i: (0, i)),
+            pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
+            pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((m, LANES), lambda i: (0, 0)),  # revisited
+        out_specs=pl.BlockSpec((block_m, LANES), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, LANES), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(w, w_hat)

@@ -9,21 +9,23 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import aligned_block
+from repro.kernels import aligned_block, cover, pad_rows_cols, row_block
 from repro.kernels.trigger.kernel import trigger_sq_pallas
 
 
 def trigger_sq(w: jax.Array, w_hat: jax.Array, *, block_n: int = 1024,
                interpret: bool = False) -> jax.Array:
-    """(m, n) x2 -> (m,) squared deviation; pads n (zero pad -> no effect)."""
+    """(m, n) x2 -> (m,) squared deviation; pads m and n up to block
+    multiples (zero pad -> no effect on the sums)."""
     m, n = w.shape
+    block_m = row_block(m)
     block_n = aligned_block(n, block_n)
-    pad = (-n) % block_n
-    if pad:
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-        w_hat = jnp.pad(w_hat, ((0, 0), (0, pad)))
-    part = trigger_sq_pallas(w, w_hat, block_n=block_n, interpret=interpret)
-    return part.sum(axis=1)
+    rows, cols = cover(m, block_m), cover(n, block_n)
+    part = trigger_sq_pallas(pad_rows_cols(w, rows, cols),
+                             pad_rows_cols(w_hat, rows, cols),
+                             block_m=block_m, block_n=block_n,
+                             interpret=interpret)
+    return part[:m].sum(axis=1)
 
 
 def trigger_sq_tree(w_tree, h_tree, *, interpret: bool = False) -> jax.Array:

@@ -7,14 +7,10 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    try:  # jax >= 0.5
-        from jax.sharding import AxisType
-    except ImportError:  # older jax: meshes are Auto-typed by default
-        return jax.make_mesh(shape, axes)
-
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -48,7 +44,20 @@ def make_fleet_mesh(n_shards: int):
     return _mk((n_shards,), ("fl",))
 
 
-# TPU v5e hardware constants used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12  # per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect (ici_bw: per link, one
+# of four).
+PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def device_peaks(device_kind: str) -> dict[str, float]:
+    """The peaks of ``device_kind``; a device that is not in the table is
+    an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None

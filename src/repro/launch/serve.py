@@ -80,6 +80,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro.api import ScenarioService
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     specs = (demo_requests(args.iters) if args.demo
              else load_requests(args.requests))

@@ -293,15 +293,8 @@ def _shard_map_moe(cfg: ArchConfig, p, xt, mesh, *, fsdp: bool = True):
         # combine: bf16 reduce-scatter over the expert shards -> seq shards
         return jax.lax.psum_scatter(out, "model", scatter_dimension=0, tiled=True)
 
-    if hasattr(jax, "shard_map"):  # jax >= 0.6: manual axes named directly
-        fn = jax.shard_map(local, mesh=mesh, in_specs=(x_spec, w_specs),
-                           out_specs=out_spec, axis_names=manual, check_vma=False)
-    else:  # older jax: experimental API takes the complementary `auto` set
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        fn = _shard_map(local, mesh=mesh, in_specs=(x_spec, w_specs),
-                        out_specs=out_spec, check_rep=False,
-                        auto=frozenset(mesh.axis_names) - manual)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(x_spec, w_specs),
+                       out_specs=out_spec, axis_names=manual, check_vma=False)
     return fn(xt, weights)
 
 

