@@ -1,0 +1,8 @@
+"""Percent of the traced window in which no operation ran on the device:
+1 - (union of the operation intervals) / window, averaged over chips."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    return 100.0 * ctx.trace.idle_share
