@@ -234,110 +234,119 @@ def step(
 
     Events 1-3 run on the canonical (m, D) flat view (one flatten at the
     top, one ``unflatten_stack`` before Event 4); only local SGD and the
-    w_hat snapshot see the pytree (DESIGN.md "Model plumbing")."""
+    w_hat snapshot see the pytree (DESIGN.md "Model plumbing").
+
+    Each part runs under a ``jax.named_scope`` -- ``efhc.event1`` (key
+    split, adjacency, churn/fault masks, new links), ``efhc.event2``
+    (deviation, triggers), ``efhc.event3`` (P, mix, rejoin, watchdog,
+    w_hat), ``efhc.event4`` (local update), ``efhc.stats`` -- so a profile
+    attributes each device op to its Event.  Scopes are op metadata only:
+    the compiled program and its numerics do not change."""
     if cfg.mix_impl not in MIX_IMPLS:
         raise ValueError(f"unknown mix_impl {cfg.mix_impl!r}; known: {MIX_IMPLS}")
     sparse = cfg.mix_impl in SPARSE_MIX_IMPLS
     m = state.bandwidths.shape[0]
-    key, k_trig, k_grad = jax.random.split(state.key, 3)
+    with jax.named_scope("efhc.event1"):
+        key, k_trig, k_grad = jax.random.split(state.key, 3)
 
-    # resource dynamics: Python-level gate -- the disabled path is the
-    # pre-resource program verbatim (no extra RNG splits, no masking ops)
-    rcfg = cfg.resources
-    dyn = rcfg is not None and rcfg.enabled
-    if dyn:
-        res = state.resources
-        r_key, k_evolve = jax.random.split(res.key)
-        up, straggle, bw_live = resources_mod.evolve(
-            rcfg, k_evolve, res.up, res.bw, state.bandwidths, m)
-        exhausted = resources_mod.exhausted_mask(rcfg, res.budget)
-        # exhausted devices see a collapsed threshold bandwidth: rho = 1/b
-        # explodes and the personalized trigger goes quiet on its own
-        bw_thresh = jnp.where(
-            exhausted, resources_mod.EXHAUSTED_BW_FRAC * state.bandwidths,
-            bw_live)
-    else:
-        bw_thresh = state.bandwidths
-        bw_live = state.bandwidths
-
-    # correlated faults: an independent Python-level gate with its own
-    # carried stream -- crash/rejoin + cluster-outage Markov bits evolve
-    # here; edge-level faults (partition window, flapping) mask below
-    fcfg = cfg.faults
-    fdyn = fcfg is not None and fcfg.enabled
-    if fdyn:
-        fstate = state.faults
-        f_key, k_fevolve = jax.random.split(fstate.key)
-        crashed, rejoined, staleness, cluster_down = faults_mod.evolve(
-            fcfg, k_fevolve, fstate.crashed, fstate.staleness,
-            fstate.cluster_down, m)
-        f_up = faults_mod.device_up(crashed, cluster_down, ftabs.labels)
-
-    wcfg = cfg.watchdog
-    wdog = wcfg is not None and wcfg.enabled
-
-    if sparse:
-        if nl is None:
-            # setup-time numpy, traced in as constants; built straight from
-            # the edge list (vectorized, never via a dense adjacency)
-            nl = graph.neighbors()
-        nbr_idx = jnp.asarray(nl.idx)
-        adj_ell = graph.adjacency_ell(state.k, nl)
+        # resource dynamics: Python-level gate -- the disabled path is the
+        # pre-resource program verbatim (no extra RNG splits, no masking ops)
+        rcfg = cfg.resources
+        dyn = rcfg is not None and rcfg.enabled
         if dyn:
-            # churn masks Events 1-3: a down endpoint removes the edge from
-            # the effective G^(k); reconnection later fires Event 1 through
-            # the ordinary prev-adjacency delta
-            adj_ell = jnp.logical_and(
-                adj_ell, jnp.logical_and(up[:, None], up[nbr_idx]))
+            res = state.resources
+            r_key, k_evolve = jax.random.split(res.key)
+            up, straggle, bw_live = resources_mod.evolve(
+                rcfg, k_evolve, res.up, res.bw, state.bandwidths, m)
+            exhausted = resources_mod.exhausted_mask(rcfg, res.budget)
+            # exhausted devices see a collapsed threshold bandwidth: rho = 1/b
+            # explodes and the personalized trigger goes quiet on its own
+            bw_thresh = jnp.where(
+                exhausted, resources_mod.EXHAUSTED_BW_FRAC * state.bandwidths,
+                bw_live)
+        else:
+            bw_thresh = state.bandwidths
+            bw_live = state.bandwidths
+
+        # correlated faults: an independent Python-level gate with its own
+        # carried stream -- crash/rejoin + cluster-outage Markov bits evolve
+        # here; edge-level faults (partition window, flapping) mask below
+        fcfg = cfg.faults
+        fdyn = fcfg is not None and fcfg.enabled
         if fdyn:
-            # crashed / clustered-out devices drop off the fabric entirely;
-            # edge faults kill individual links on their own schedule
-            adj_ell = jnp.logical_and(
-                adj_ell, jnp.logical_and(f_up[:, None], f_up[nbr_idx]))
-            if fcfg.edge_faults:
+            fstate = state.faults
+            f_key, k_fevolve = jax.random.split(fstate.key)
+            crashed, rejoined, staleness, cluster_down = faults_mod.evolve(
+                fcfg, k_fevolve, fstate.crashed, fstate.staleness,
+                fstate.cluster_down, m)
+            f_up = faults_mod.device_up(crashed, cluster_down, ftabs.labels)
+
+        wcfg = cfg.watchdog
+        wdog = wcfg is not None and wcfg.enabled
+
+        if sparse:
+            if nl is None:
+                # setup-time numpy, traced in as constants; built straight from
+                # the edge list (vectorized, never via a dense adjacency)
+                nl = graph.neighbors()
+            nbr_idx = jnp.asarray(nl.idx)
+            adj_ell = graph.adjacency_ell(state.k, nl)
+            if dyn:
+                # churn masks Events 1-3: a down endpoint removes the edge from
+                # the effective G^(k); reconnection later fires Event 1 through
+                # the ordinary prev-adjacency delta
                 adj_ell = jnp.logical_and(
-                    adj_ell, faults_mod.edge_keep(fcfg, state.k, ftabs))
-        # dense view for StepAux consumers only; dead code whenever the ys
-        # stick to the ELL-derived row sums (trace="summary")
-        adj = topology.scatter_ell(nbr_idx, adj_ell)
-    else:
-        adj = graph.adjacency(state.k)
-        if dyn:
-            adj = jnp.logical_and(
-                adj, jnp.logical_and(up[:, None], up[None, :]))
-        if fdyn:
-            adj = jnp.logical_and(
-                adj, jnp.logical_and(f_up[:, None], f_up[None, :]))
-            if fcfg.edge_faults:
+                    adj_ell, jnp.logical_and(up[:, None], up[nbr_idx]))
+            if fdyn:
+                # crashed / clustered-out devices drop off the fabric entirely;
+                # edge faults kill individual links on their own schedule
+                adj_ell = jnp.logical_and(
+                    adj_ell, jnp.logical_and(f_up[:, None], f_up[nbr_idx]))
+                if fcfg.edge_faults:
+                    adj_ell = jnp.logical_and(
+                        adj_ell, faults_mod.edge_keep(fcfg, state.k, ftabs))
+            # dense view for StepAux consumers only; dead code whenever the ys
+            # stick to the ELL-derived row sums (trace="summary")
+            adj = topology.scatter_ell(nbr_idx, adj_ell)
+        else:
+            adj = graph.adjacency(state.k)
+            if dyn:
                 adj = jnp.logical_and(
-                    adj, faults_mod.edge_keep(fcfg, state.k, ftabs))
+                    adj, jnp.logical_and(up[:, None], up[None, :]))
+            if fdyn:
+                adj = jnp.logical_and(
+                    adj, jnp.logical_and(f_up[:, None], f_up[None, :]))
+                if fcfg.edge_faults:
+                    adj = jnp.logical_and(
+                        adj, faults_mod.edge_keep(fcfg, state.k, ftabs))
 
     # ---- Event 2: broadcast triggers -------------------------------------
-    w_flat = _flatten_stack(state.w)
-    w_hat_flat = _flatten_stack(state.w_hat)
-    gamma_k = cfg.gamma(state.k) if cfg.gamma is not None else alpha_k
-    if cfg.mix_impl == "pallas":
-        # fused deviation kernel: streams (w, w_hat) tiles through VMEM
-        # without materializing the delta in HBM
-        n_model = w_flat.shape[1]
-        sq = trigger_ops.trigger_sq(w_flat, w_hat_flat,
-                                    interpret=cfg.pallas_interpret())
-        dev = jnp.sqrt(sq / n_model)
-    else:
-        dev = triggers.rms_deviation(w_flat, w_hat_flat)
-    v = triggers.broadcast_events(
-        cfg.trigger, dev=dev,
-        bandwidths=bw_thresh, gamma_k=gamma_k, key=k_trig,
-        policy_idx=policy_idx,
-    )
-    if dyn:
-        # hard mask: down and budget-exhausted devices fire nothing -- this
-        # also stops the threshold-blind policies (ZT/gossip) from spending
-        # past their budget
-        v = jnp.logical_and(v, jnp.logical_and(up, ~exhausted))
-    if fdyn:
-        # crashed / clustered-out devices broadcast nothing
-        v = jnp.logical_and(v, f_up)
+    with jax.named_scope("efhc.event2"):
+        w_flat = _flatten_stack(state.w)
+        w_hat_flat = _flatten_stack(state.w_hat)
+        gamma_k = cfg.gamma(state.k) if cfg.gamma is not None else alpha_k
+        if cfg.mix_impl == "pallas":
+            # fused deviation kernel: streams (w, w_hat) tiles through VMEM
+            # without materializing the delta in HBM
+            n_model = w_flat.shape[1]
+            sq = trigger_ops.trigger_sq(w_flat, w_hat_flat,
+                                        interpret=cfg.pallas_interpret())
+            dev = jnp.sqrt(sq / n_model)
+        else:
+            dev = triggers.rms_deviation(w_flat, w_hat_flat)
+        v = triggers.broadcast_events(
+            cfg.trigger, dev=dev,
+            bandwidths=bw_thresh, gamma_k=gamma_k, key=k_trig,
+            policy_idx=policy_idx,
+        )
+        if dyn:
+            # hard mask: down and budget-exhausted devices fire nothing -- this
+            # also stops the threshold-blind policies (ZT/gossip) from spending
+            # past their budget
+            v = jnp.logical_and(v, jnp.logical_and(up, ~exhausted))
+        if fdyn:
+            # crashed / clustered-out devices broadcast nothing
+            v = jnp.logical_and(v, f_up)
 
     # ---- Event 1: neighbor connection ------------------------------------
     # Links that newly appeared vs k-1 exchange parameters unconditionally.
@@ -345,144 +354,151 @@ def step(
     if sparse:
         # same event algebra, per neighbor-list slot: prev_adj is the ELL
         # mask of G^(k-1), v_ij = v_i | v_j gathers the neighbor's trigger
-        new_links_ell = jnp.logical_and(adj_ell, ~state.prev_adj)
-        vv_ell = jnp.logical_or(v[:, None], v[nbr_idx])
-        comm_ell = jnp.logical_or(jnp.logical_and(vv_ell, adj_ell), new_links_ell)
-        p_diag, p_off = mixing.build_p_ell(nbr_idx, adj_ell, comm_ell)
-        if cfg.mix_impl == "sparse_pallas":
-            w_mixed_flat = mixing_ops.mix_sparse(nbr_idx, p_diag, p_off, w_flat,
-                                                 interpret=cfg.pallas_interpret())
-        elif cfg.mix_impl == "sparse_delta":
-            w_mixed_flat = consensus.mix_delta_sparse(nbr_idx, p_off, w_flat)
-        else:
-            w_mixed_flat = consensus.mix_sparse(nbr_idx, p_diag, p_off, w_flat)
-        comm = topology.scatter_ell(nbr_idx, comm_ell)  # DCE-able, like adj
-        p = topology.scatter_ell(nbr_idx, p_off) + jnp.diag(p_diag)
-        used_i = comm_ell.sum(axis=1, dtype=jnp.int32)
-        deg_i = adj_ell.sum(axis=1, dtype=jnp.int32)
-        prev_adj_next = adj_ell
+        with jax.named_scope("efhc.event1"):
+            new_links_ell = jnp.logical_and(adj_ell, ~state.prev_adj)
+        with jax.named_scope("efhc.event3"):
+            vv_ell = jnp.logical_or(v[:, None], v[nbr_idx])
+            comm_ell = jnp.logical_or(jnp.logical_and(vv_ell, adj_ell), new_links_ell)
+            p_diag, p_off = mixing.build_p_ell(nbr_idx, adj_ell, comm_ell)
+            if cfg.mix_impl == "sparse_pallas":
+                w_mixed_flat = mixing_ops.mix_sparse(nbr_idx, p_diag, p_off, w_flat,
+                                                     interpret=cfg.pallas_interpret())
+            elif cfg.mix_impl == "sparse_delta":
+                w_mixed_flat = consensus.mix_delta_sparse(nbr_idx, p_off, w_flat)
+            else:
+                w_mixed_flat = consensus.mix_sparse(nbr_idx, p_diag, p_off, w_flat)
+            comm = topology.scatter_ell(nbr_idx, comm_ell)  # DCE-able, like adj
+            p = topology.scatter_ell(nbr_idx, p_off) + jnp.diag(p_diag)
+            used_i = comm_ell.sum(axis=1, dtype=jnp.int32)
+            deg_i = adj_ell.sum(axis=1, dtype=jnp.int32)
+            prev_adj_next = adj_ell
     else:
-        new_links = jnp.logical_and(adj, ~state.prev_adj)
-        comm = jnp.logical_or(triggers.communication_matrix(v, adj), new_links)
-        p = mixing.build_p(adj, comm)
-        if cfg.mix_impl == "pallas":
-            w_mixed_flat = mixing_ops.mix(p, w_flat, interpret=cfg.pallas_interpret())
-        elif cfg.mix_impl == "delta":
-            w_mixed_flat = consensus.mix_delta_dense(p, w_flat)
+        with jax.named_scope("efhc.event1"):
+            new_links = jnp.logical_and(adj, ~state.prev_adj)
+        with jax.named_scope("efhc.event3"):
+            comm = jnp.logical_or(triggers.communication_matrix(v, adj), new_links)
+            p = mixing.build_p(adj, comm)
+            if cfg.mix_impl == "pallas":
+                w_mixed_flat = mixing_ops.mix(p, w_flat, interpret=cfg.pallas_interpret())
+            elif cfg.mix_impl == "delta":
+                w_mixed_flat = consensus.mix_delta_dense(p, w_flat)
+            else:
+                w_mixed_flat = consensus.mix_dense(p, w_flat)
+            used_i = comm.sum(axis=1, dtype=jnp.int32)
+            deg_i = adj.sum(axis=1, dtype=jnp.int32)
+            prev_adj_next = adj
+
+    with jax.named_scope("efhc.event3"):
+        if fdyn and fcfg.warm_start:
+            # staleness-aware rejoin (ROADMAP recovery item (d)): a device
+            # rejoining this iteration replaces its frozen stale model with the
+            # plain average of its *live* neighbors' pre-mix models, instead of
+            # re-entering consensus self-weighted by Metropolis p_ii.  Computed
+            # from w_flat (pre-patch values), so multiple simultaneous rejoins
+            # are order-independent -- and shard-consistent.
+            if sparse:
+                nb_sum = jnp.where(adj_ell[..., None], w_flat[nbr_idx], 0.0
+                                   ).sum(axis=1)
+                nb_cnt = adj_ell.sum(axis=1, dtype=jnp.float32)
+            else:
+                a_f = adj.astype(jnp.float32)
+                nb_sum = jnp.matmul(a_f, w_flat, precision=consensus.MIX_PRECISION)
+                nb_cnt = a_f.sum(axis=1)
+            nb_avg = nb_sum / jnp.maximum(nb_cnt, 1.0)[:, None]
+            patch = jnp.logical_and(rejoined, nb_cnt > 0)
+            w_mixed_flat = jnp.where(patch[:, None], nb_avg, w_mixed_flat)
+
+        # in-scan B-connectivity watchdog over the realized information-flow
+        # edges E'^(k); under a dense mix_impl the (m, m) comm matrix is
+        # gathered into ELL slots first (the engines pass ``nl`` whenever the
+        # watchdog is on)
+        if wdog:
+            if sparse:
+                w_idx, w_comm = nbr_idx, comm_ell
+            else:
+                w_idx = jnp.asarray(nl.idx)
+                w_comm = flow_mod.comm_ell_from_dense(
+                    comm, w_idx, jnp.asarray(nl.mask))
+            wd_age, window_connected, window_needed = flow_mod.watchdog_step(
+                wcfg, w_idx, w_comm, state.watchdog.age)
+            wd_new = flow_mod.WatchdogState(age=wd_age)
         else:
-            w_mixed_flat = consensus.mix_dense(p, w_flat)
-        used_i = comm.sum(axis=1, dtype=jnp.int32)
-        deg_i = adj.sum(axis=1, dtype=jnp.int32)
-        prev_adj_next = adj
+            wd_new = state.watchdog
+            window_connected = jnp.ones((), bool)
+            window_needed = jnp.zeros((), jnp.int32)
 
-    if fdyn and fcfg.warm_start:
-        # staleness-aware rejoin (ROADMAP recovery item (d)): a device
-        # rejoining this iteration replaces its frozen stale model with the
-        # plain average of its *live* neighbors' pre-mix models, instead of
-        # re-entering consensus self-weighted by Metropolis p_ii.  Computed
-        # from w_flat (pre-patch values), so multiple simultaneous rejoins
-        # are order-independent -- and shard-consistent.
-        if sparse:
-            nb_sum = jnp.where(adj_ell[..., None], w_flat[nbr_idx], 0.0
-                               ).sum(axis=1)
-            nb_cnt = adj_ell.sum(axis=1, dtype=jnp.float32)
-        else:
-            a_f = adj.astype(jnp.float32)
-            nb_sum = jnp.matmul(a_f, w_flat, precision=consensus.MIX_PRECISION)
-            nb_cnt = a_f.sum(axis=1)
-        nb_avg = nb_sum / jnp.maximum(nb_cnt, 1.0)[:, None]
-        patch = jnp.logical_and(rejoined, nb_cnt > 0)
-        w_mixed_flat = jnp.where(patch[:, None], nb_avg, w_mixed_flat)
+        # w_hat update: devices that broadcast snapshot their *pre-mix* model
+        # (Alg. 1 line 12: w_hat^(k+1) = w^(k))
+        def upd_hat(h, wcur):
+            mask = v.reshape((m,) + (1,) * (wcur.ndim - 1))
+            return jnp.where(mask, wcur, h)
 
-    # in-scan B-connectivity watchdog over the realized information-flow
-    # edges E'^(k); under a dense mix_impl the (m, m) comm matrix is
-    # gathered into ELL slots first (the engines pass ``nl`` whenever the
-    # watchdog is on)
-    if wdog:
-        if sparse:
-            w_idx, w_comm = nbr_idx, comm_ell
-        else:
-            w_idx = jnp.asarray(nl.idx)
-            w_comm = flow_mod.comm_ell_from_dense(
-                comm, w_idx, jnp.asarray(nl.mask))
-        wd_age, window_connected, window_needed = flow_mod.watchdog_step(
-            wcfg, w_idx, w_comm, state.watchdog.age)
-        wd_new = flow_mod.WatchdogState(age=wd_age)
-    else:
-        wd_new = state.watchdog
-        window_connected = jnp.ones((), bool)
-        window_needed = jnp.zeros((), jnp.int32)
-
-    # w_hat update: devices that broadcast snapshot their *pre-mix* model
-    # (Alg. 1 line 12: w_hat^(k+1) = w^(k))
-    def upd_hat(h, wcur):
-        mask = v.reshape((m,) + (1,) * (wcur.ndim - 1))
-        return jnp.where(mask, wcur, h)
-
-    w_hat_new = jax.tree.map(upd_hat, state.w_hat, state.w)
+        w_hat_new = jax.tree.map(upd_hat, state.w_hat, state.w)
 
     # ---- Event 4: local SGD (on the unflattened pytree) -------------------
-    w_mixed = unflatten_stack(w_mixed_flat, state.w)
-    grad_keys = jax.random.split(k_grad, m)
-    loss, grads = jax.vmap(grad_fn, in_axes=(0, 0, 0))(w_mixed, grad_keys, batch)
-    if opt_update is None:
-        w_new = jax.tree.map(lambda wm, g: (wm.astype(jnp.float32) - alpha_k * g.astype(jnp.float32)).astype(wm.dtype), w_mixed, grads)
-        opt_state_new = state.opt_state
-    else:
-        w_new, opt_state_new = opt_update(grads, state.opt_state, w_mixed, alpha_k)
-    if dyn or fdyn:
-        # stragglers delay Event 4 (carry the mixed model); down / crashed
-        # devices do not compute at all -- both keep their pre-update rows
-        # + opt state (a crashed device's edges are all masked, so its
-        # "mixed" row IS its frozen theta)
-        upd = None
-        if dyn:
-            upd = jnp.logical_and(up, ~straggle)
-        if fdyn:
-            upd = f_up if upd is None else jnp.logical_and(upd, f_up)
-        w_new = _mask_update_rows(upd, m, w_new, w_mixed)
-        opt_state_new = _mask_update_rows(upd, m, opt_state_new,
-                                          state.opt_state)
+    with jax.named_scope("efhc.event4"):
+        w_mixed = unflatten_stack(w_mixed_flat, state.w)
+        grad_keys = jax.random.split(k_grad, m)
+        loss, grads = jax.vmap(grad_fn, in_axes=(0, 0, 0))(w_mixed, grad_keys, batch)
+        if opt_update is None:
+            w_new = jax.tree.map(lambda wm, g: (wm.astype(jnp.float32) - alpha_k * g.astype(jnp.float32)).astype(wm.dtype), w_mixed, grads)
+            opt_state_new = state.opt_state
+        else:
+            w_new, opt_state_new = opt_update(grads, state.opt_state, w_mixed, alpha_k)
+        if dyn or fdyn:
+            # stragglers delay Event 4 (carry the mixed model); down / crashed
+            # devices do not compute at all -- both keep their pre-update rows
+            # + opt state (a crashed device's edges are all masked, so its
+            # "mixed" row IS its frozen theta)
+            upd = None
+            if dyn:
+                upd = jnp.logical_and(up, ~straggle)
+            if fdyn:
+                upd = f_up if upd is None else jnp.logical_and(upd, f_up)
+            w_new = _mask_update_rows(upd, m, w_new, w_mixed)
+            opt_state_new = _mask_update_rows(upd, m, opt_state_new,
+                                              state.opt_state)
 
     # ---- paper metrics (Sec. IV-A) ----------------------------------------
-    deg = deg_i.astype(jnp.float32)
-    used = used_i.astype(jnp.float32)
-    frac = jnp.where(deg > 0, used / jnp.maximum(deg, 1.0), 0.0)
-    tx_time = jnp.mean(frac * model_dim / bw_live)
-    # resource utilization (Sec. IV-A): fraction of the network's aggregate
-    # one-hop link capacity consumed this iteration -- bits pushed over the
-    # activated links vs. the capacity of every physical link.  A ratio of
-    # sums, NOT the mean of per-device ratios (that would collapse back into
-    # tx_time): heterogeneous bandwidths weight the two differently.
-    capacity = jnp.sum(deg * bw_live)
-    util = jnp.sum(used * model_dim) / jnp.maximum(capacity, 1e-12)
+    with jax.named_scope("efhc.stats"):
+        deg = deg_i.astype(jnp.float32)
+        used = used_i.astype(jnp.float32)
+        frac = jnp.where(deg > 0, used / jnp.maximum(deg, 1.0), 0.0)
+        tx_time = jnp.mean(frac * model_dim / bw_live)
+        # resource utilization (Sec. IV-A): fraction of the network's aggregate
+        # one-hop link capacity consumed this iteration -- bits pushed over the
+        # activated links vs. the capacity of every physical link.  A ratio of
+        # sums, NOT the mean of per-device ratios (that would collapse back into
+        # tx_time): heterogeneous bandwidths weight the two differently.
+        capacity = jnp.sum(deg * bw_live)
+        util = jnp.sum(used * model_dim) / jnp.maximum(capacity, 1e-12)
 
-    # consensus error on the post-update stack (the paper's ||W - 1 w_bar||_F^2)
-    w_new_flat = _flatten_stack(w_new)
-    consensus_err = jnp.sum((w_new_flat - w_new_flat.mean(0)) ** 2)
+        # consensus error on the post-update stack (the paper's ||W - 1 w_bar||_F^2)
+        w_new_flat = _flatten_stack(w_new)
+        consensus_err = jnp.sum((w_new_flat - w_new_flat.mean(0)) ** 2)
 
-    if dyn:
-        # budget debit: each realized broadcast ships one model payload
-        n_bytes = float(accounting.model_bytes(model_dim))
-        res_new = resources_mod.ResourceState(
-            bw=bw_live, budget=res.budget - n_bytes * v.astype(jnp.float32),
-            up=up, key=r_key)
-        down_count = jnp.sum(~up).astype(jnp.int32)
-        exhausted_count = jnp.sum(exhausted).astype(jnp.int32)
-    else:
-        res_new = state.resources
-        down_count = jnp.zeros((), jnp.int32)
-        exhausted_count = jnp.zeros((), jnp.int32)
+        if dyn:
+            # budget debit: each realized broadcast ships one model payload
+            n_bytes = float(accounting.model_bytes(model_dim))
+            res_new = resources_mod.ResourceState(
+                bw=bw_live, budget=res.budget - n_bytes * v.astype(jnp.float32),
+                up=up, key=r_key)
+            down_count = jnp.sum(~up).astype(jnp.int32)
+            exhausted_count = jnp.sum(exhausted).astype(jnp.int32)
+        else:
+            res_new = state.resources
+            down_count = jnp.zeros((), jnp.int32)
+            exhausted_count = jnp.zeros((), jnp.int32)
 
-    if fdyn:
-        f_new = faults_mod.FaultState(crashed=crashed, staleness=staleness,
-                                      cluster_down=cluster_down, key=f_key)
-        fault_down_count = jnp.sum(~f_up).astype(jnp.int32)
-        stale_max = jnp.max(staleness)
-    else:
-        f_new = state.faults
-        fault_down_count = jnp.zeros((), jnp.int32)
-        stale_max = jnp.zeros((), jnp.int32)
+        if fdyn:
+            f_new = faults_mod.FaultState(crashed=crashed, staleness=staleness,
+                                          cluster_down=cluster_down, key=f_key)
+            fault_down_count = jnp.sum(~f_up).astype(jnp.int32)
+            stale_max = jnp.max(staleness)
+        else:
+            f_new = state.faults
+            fault_down_count = jnp.zeros((), jnp.int32)
+            stale_max = jnp.zeros((), jnp.int32)
 
     new_state = EFHCState(
         w=w_new, w_hat=w_hat_new, k=state.k + 1, prev_adj=prev_adj_next,
@@ -548,8 +564,9 @@ def halo_exchange(ctx: ShardCtx, axis_name: str, x: jax.Array) -> jax.Array:
     the boundary rows (``send_idx``) and pick this shard's halo out of the
     flat (S * B_max, ...) result at ``recv_src``.  Pad slots carry row
     0 / position 0 junk; every consumer masks or zero-weights them."""
-    gath = jax.lax.all_gather(x[ctx.send_idx], axis_name)
-    return gath.reshape((-1,) + gath.shape[2:])[ctx.recv_src]
+    with jax.named_scope("efhc.halo"):
+        gath = jax.lax.all_gather(x[ctx.send_idx], axis_name)
+        return gath.reshape((-1,) + gath.shape[2:])[ctx.recv_src]
 
 
 def step_sharded(
@@ -590,194 +607,204 @@ def step_sharded(
     (m, n) stack per iteration would defeat the partitioning, so it is a
     hierarchical psum (mean via column psum, then a psum of local squared
     deviations) -- equal to the single-device value up to fp32 summation
-    order, and tested with tolerance, never bit-compared."""
+    order, and tested with tolerance, never bit-compared.
+
+    Named scopes as in ``step``, plus ``efhc.halo`` around every
+    ``halo_exchange`` (innermost, also where an Event's masks call it)."""
     ms = state.bandwidths.shape[0]
-    key, k_trig, k_grad = jax.random.split(state.key, 3)
     ex = lambda x: halo_exchange(ctx, axis_name, x)
+    with jax.named_scope("efhc.event1"):
+        key, k_trig, k_grad = jax.random.split(state.key, 3)
 
-    # resource dynamics: the same Python-level gate as ``step``; draws are
-    # positional (m,) sliced by ``ctx.owned`` so every shard count realizes
-    # the identical per-device stream (DESIGN.md "Resource dynamics")
-    rcfg = cfg.resources
-    dyn = rcfg is not None and rcfg.enabled
-    if dyn:
-        res = state.resources
-        r_key, k_evolve = jax.random.split(res.key)
-        up, straggle, bw_live = resources_mod.evolve(
-            rcfg, k_evolve, res.up, res.bw, state.bandwidths, m,
-            rows=ctx.owned)
-        exhausted = resources_mod.exhausted_mask(rcfg, res.budget)
-        bw_thresh = jnp.where(
-            exhausted, resources_mod.EXHAUSTED_BW_FRAC * state.bandwidths,
-            bw_live)
-    else:
-        bw_thresh = state.bandwidths
-        bw_live = state.bandwidths
+        # resource dynamics: the same Python-level gate as ``step``; draws are
+        # positional (m,) sliced by ``ctx.owned`` so every shard count realizes
+        # the identical per-device stream (DESIGN.md "Resource dynamics")
+        rcfg = cfg.resources
+        dyn = rcfg is not None and rcfg.enabled
+        if dyn:
+            res = state.resources
+            r_key, k_evolve = jax.random.split(res.key)
+            up, straggle, bw_live = resources_mod.evolve(
+                rcfg, k_evolve, res.up, res.bw, state.bandwidths, m,
+                rows=ctx.owned)
+            exhausted = resources_mod.exhausted_mask(rcfg, res.budget)
+            bw_thresh = jnp.where(
+                exhausted, resources_mod.EXHAUSTED_BW_FRAC * state.bandwidths,
+                bw_live)
+        else:
+            bw_thresh = state.bandwidths
+            bw_live = state.bandwidths
 
-    # correlated faults: per-device draws are positional (m,) sliced by
-    # ``ctx.owned``; cluster bits evolve from the replicated global key, so
-    # every shard realizes the identical outage pattern
-    fcfg = cfg.faults
-    fdyn = fcfg is not None and fcfg.enabled
-    if fdyn:
-        fstate = state.faults
-        f_key, k_fevolve = jax.random.split(fstate.key)
-        crashed, rejoined, staleness, cluster_down = faults_mod.evolve(
-            fcfg, k_fevolve, fstate.crashed, fstate.staleness,
-            fstate.cluster_down, m, rows=ctx.owned)
-        f_up = faults_mod.device_up(crashed, cluster_down, ftabs.labels)
+        # correlated faults: per-device draws are positional (m,) sliced by
+        # ``ctx.owned``; cluster bits evolve from the replicated global key, so
+        # every shard realizes the identical outage pattern
+        fcfg = cfg.faults
+        fdyn = fcfg is not None and fcfg.enabled
+        if fdyn:
+            fstate = state.faults
+            f_key, k_fevolve = jax.random.split(fstate.key)
+            crashed, rejoined, staleness, cluster_down = faults_mod.evolve(
+                fcfg, k_fevolve, fstate.crashed, fstate.staleness,
+                fstate.cluster_down, m, rows=ctx.owned)
+            f_up = faults_mod.device_up(crashed, cluster_down, ftabs.labels)
 
-    wcfg = cfg.watchdog
-    wdog = wcfg is not None and wcfg.enabled
+        wcfg = cfg.watchdog
+        wdog = wcfg is not None and wcfg.enabled
 
-    adj_ell = graph.adjacency_ell_rows(state.k, ctx.nbr_gid, ctx.mask, ctx.owned)
-    if dyn:
-        # churn masks Events 1-3; neighbor liveness arrives over the halo
-        # (pad slots carry junk up-bits, but adj_ell is already False there)
-        up_buf = jnp.concatenate([up, ex(up)])
-        adj_ell = jnp.logical_and(
-            adj_ell, jnp.logical_and(up[:, None], up_buf[ctx.nbr_loc]))
-    if fdyn:
-        f_up_buf = jnp.concatenate([f_up, ex(f_up)])
-        adj_ell = jnp.logical_and(
-            adj_ell, jnp.logical_and(f_up[:, None], f_up_buf[ctx.nbr_loc]))
-        if fcfg.edge_faults:
-            # edge tables are keyed by canonical global edge id, so the
-            # shard's rows see the identical (k, edge) schedule
+        adj_ell = graph.adjacency_ell_rows(state.k, ctx.nbr_gid, ctx.mask, ctx.owned)
+        if dyn:
+            # churn masks Events 1-3; neighbor liveness arrives over the halo
+            # (pad slots carry junk up-bits, but adj_ell is already False there)
+            up_buf = jnp.concatenate([up, ex(up)])
             adj_ell = jnp.logical_and(
-                adj_ell, faults_mod.edge_keep(fcfg, state.k, ftabs))
-    deg_i = adj_ell.sum(axis=1, dtype=jnp.int32)
+                adj_ell, jnp.logical_and(up[:, None], up_buf[ctx.nbr_loc]))
+        if fdyn:
+            f_up_buf = jnp.concatenate([f_up, ex(f_up)])
+            adj_ell = jnp.logical_and(
+                adj_ell, jnp.logical_and(f_up[:, None], f_up_buf[ctx.nbr_loc]))
+            if fcfg.edge_faults:
+                # edge tables are keyed by canonical global edge id, so the
+                # shard's rows see the identical (k, edge) schedule
+                adj_ell = jnp.logical_and(
+                    adj_ell, faults_mod.edge_keep(fcfg, state.k, ftabs))
+        deg_i = adj_ell.sum(axis=1, dtype=jnp.int32)
 
     # ---- Event 2: broadcast triggers (local rows) ------------------------
-    w_flat = _flatten_stack(state.w)
-    w_hat_flat = _flatten_stack(state.w_hat)
-    gamma_k = cfg.gamma(state.k) if cfg.gamma is not None else alpha_k
-    dev = triggers.rms_deviation(w_flat, w_hat_flat)
-    branches = triggers.policy_branches_rows(cfg.trigger, m, ctx.owned)
-    if policy_idx is None:
-        v = branches[triggers.policy_index(cfg.trigger.policy)](
-            dev, bw_thresh, gamma_k, k_trig)
-    else:
-        v = jax.lax.switch(policy_idx, branches,
-                           dev, bw_thresh, gamma_k, k_trig)
-    if dyn:
-        # hard mask before the halo ships v: down / exhausted devices fire
-        # nothing, and their neighbors must agree
-        v = jnp.logical_and(v, jnp.logical_and(up, ~exhausted))
-    if fdyn:
-        v = jnp.logical_and(v, f_up)
+    with jax.named_scope("efhc.event2"):
+        w_flat = _flatten_stack(state.w)
+        w_hat_flat = _flatten_stack(state.w_hat)
+        gamma_k = cfg.gamma(state.k) if cfg.gamma is not None else alpha_k
+        dev = triggers.rms_deviation(w_flat, w_hat_flat)
+        branches = triggers.policy_branches_rows(cfg.trigger, m, ctx.owned)
+        if policy_idx is None:
+            v = branches[triggers.policy_index(cfg.trigger.policy)](
+                dev, bw_thresh, gamma_k, k_trig)
+        else:
+            v = jax.lax.switch(policy_idx, branches,
+                               dev, bw_thresh, gamma_k, k_trig)
+        if dyn:
+            # hard mask before the halo ships v: down / exhausted devices fire
+            # nothing, and their neighbors must agree
+            v = jnp.logical_and(v, jnp.logical_and(up, ~exhausted))
+        if fdyn:
+            v = jnp.logical_and(v, f_up)
 
     # ---- halo exchange: boundary rows of (w_flat, v, deg) ----------------
     # the halo ships the canonical (ms, D) flat rows -- one gathered array
     # regardless of how many leaves the model pytree has
-    w_halo_flat = ex(w_flat)
-    v_buf = jnp.concatenate([v, ex(v)])
-    deg_buf = jnp.concatenate([deg_i, ex(deg_i)])
+    with jax.named_scope("efhc.halo"):
+        w_halo_flat = ex(w_flat)
+        v_buf = jnp.concatenate([v, ex(v)])
+        deg_buf = jnp.concatenate([deg_i, ex(deg_i)])
 
     # ---- Events 1 + 3: new links, information-flow edges, mixing ---------
-    new_links_ell = jnp.logical_and(adj_ell, ~state.prev_adj)
-    vv_ell = jnp.logical_or(v[:, None], v_buf[ctx.nbr_loc])
-    comm_ell = jnp.logical_or(jnp.logical_and(vv_ell, adj_ell), new_links_ell)
-    p_diag, p_off = mixing.build_p_ell_halo(ctx.nbr_loc, adj_ell, comm_ell,
-                                            deg_buf)
-    w_mixed_flat = consensus.mix_sparse_halo(ctx.nbr_loc, p_diag, p_off,
-                                             w_flat, w_halo_flat)
-    used_i = comm_ell.sum(axis=1, dtype=jnp.int32)
+    with jax.named_scope("efhc.event1"):
+        new_links_ell = jnp.logical_and(adj_ell, ~state.prev_adj)
+    with jax.named_scope("efhc.event3"):
+        vv_ell = jnp.logical_or(v[:, None], v_buf[ctx.nbr_loc])
+        comm_ell = jnp.logical_or(jnp.logical_and(vv_ell, adj_ell), new_links_ell)
+        p_diag, p_off = mixing.build_p_ell_halo(ctx.nbr_loc, adj_ell, comm_ell,
+                                                deg_buf)
+        w_mixed_flat = consensus.mix_sparse_halo(ctx.nbr_loc, p_diag, p_off,
+                                                 w_flat, w_halo_flat)
+        used_i = comm_ell.sum(axis=1, dtype=jnp.int32)
 
-    if fdyn and fcfg.warm_start:
-        # staleness-aware rejoin: neighbor values come out of the [own;
-        # halo] buffer of *pre-patch* rows -- the identical slot-order sum
-        # the single-device sparse impl performs, so owned-row trajectories
-        # stay bit-exact
-        w_buf = jnp.concatenate([w_flat, w_halo_flat])
-        nb_sum = jnp.where(adj_ell[..., None], w_buf[ctx.nbr_loc], 0.0
-                           ).sum(axis=1)
-        nb_cnt = adj_ell.sum(axis=1, dtype=jnp.float32)
-        nb_avg = nb_sum / jnp.maximum(nb_cnt, 1.0)[:, None]
-        patch = jnp.logical_and(rejoined, nb_cnt > 0)
-        w_mixed_flat = jnp.where(patch[:, None], nb_avg, w_mixed_flat)
+        if fdyn and fcfg.warm_start:
+            # staleness-aware rejoin: neighbor values come out of the [own;
+            # halo] buffer of *pre-patch* rows -- the identical slot-order sum
+            # the single-device sparse impl performs, so owned-row trajectories
+            # stay bit-exact
+            w_buf = jnp.concatenate([w_flat, w_halo_flat])
+            nb_sum = jnp.where(adj_ell[..., None], w_buf[ctx.nbr_loc], 0.0
+                               ).sum(axis=1)
+            nb_cnt = adj_ell.sum(axis=1, dtype=jnp.float32)
+            nb_avg = nb_sum / jnp.maximum(nb_cnt, 1.0)[:, None]
+            patch = jnp.logical_and(rejoined, nb_cnt > 0)
+            w_mixed_flat = jnp.where(patch[:, None], nb_avg, w_mixed_flat)
 
-    if wdog:
-        wd_age, window_connected, window_needed = flow_mod.watchdog_step_halo(
-            wcfg, m, ctx.nbr_loc, ctx.owned, comm_ell, state.watchdog.age,
-            ex, axis_name)
-        wd_new = flow_mod.WatchdogState(age=wd_age)
-    else:
-        wd_new = state.watchdog
-        window_connected = jnp.ones((), bool)
-        window_needed = jnp.zeros((), jnp.int32)
+        if wdog:
+            wd_age, window_connected, window_needed = flow_mod.watchdog_step_halo(
+                wcfg, m, ctx.nbr_loc, ctx.owned, comm_ell, state.watchdog.age,
+                ex, axis_name)
+            wd_new = flow_mod.WatchdogState(age=wd_age)
+        else:
+            wd_new = state.watchdog
+            window_connected = jnp.ones((), bool)
+            window_needed = jnp.zeros((), jnp.int32)
 
-    def upd_hat(h, wcur):
-        mask = v.reshape((ms,) + (1,) * (wcur.ndim - 1))
-        return jnp.where(mask, wcur, h)
+        def upd_hat(h, wcur):
+            mask = v.reshape((ms,) + (1,) * (wcur.ndim - 1))
+            return jnp.where(mask, wcur, h)
 
-    w_hat_new = jax.tree.map(upd_hat, state.w_hat, state.w)
+        w_hat_new = jax.tree.map(upd_hat, state.w_hat, state.w)
 
     # ---- Event 4: local SGD (global per-device key stream, sliced) -------
-    w_mixed = unflatten_stack(w_mixed_flat, state.w)
-    grad_keys = jax.random.split(k_grad, m)[ctx.owned]
-    loss, grads = jax.vmap(grad_fn, in_axes=(0, 0, 0))(w_mixed, grad_keys, batch)
-    if opt_update is None:
-        w_new = jax.tree.map(
-            lambda wm, g: (wm.astype(jnp.float32)
-                           - alpha_k * g.astype(jnp.float32)).astype(wm.dtype),
-            w_mixed, grads)
-        opt_state_new = state.opt_state
-    else:
-        w_new, opt_state_new = opt_update(grads, state.opt_state, w_mixed,
-                                          alpha_k)
-    if dyn or fdyn:
-        upd = None
-        if dyn:
-            upd = jnp.logical_and(up, ~straggle)
-        if fdyn:
-            upd = f_up if upd is None else jnp.logical_and(upd, f_up)
-        w_new = _mask_update_rows(upd, ms, w_new, w_mixed)
-        opt_state_new = _mask_update_rows(upd, ms, opt_state_new,
-                                          state.opt_state)
+    with jax.named_scope("efhc.event4"):
+        w_mixed = unflatten_stack(w_mixed_flat, state.w)
+        grad_keys = jax.random.split(k_grad, m)[ctx.owned]
+        loss, grads = jax.vmap(grad_fn, in_axes=(0, 0, 0))(w_mixed, grad_keys, batch)
+        if opt_update is None:
+            w_new = jax.tree.map(
+                lambda wm, g: (wm.astype(jnp.float32)
+                               - alpha_k * g.astype(jnp.float32)).astype(wm.dtype),
+                w_mixed, grads)
+            opt_state_new = state.opt_state
+        else:
+            w_new, opt_state_new = opt_update(grads, state.opt_state, w_mixed,
+                                              alpha_k)
+        if dyn or fdyn:
+            upd = None
+            if dyn:
+                upd = jnp.logical_and(up, ~straggle)
+            if fdyn:
+                upd = f_up if upd is None else jnp.logical_and(upd, f_up)
+            w_new = _mask_update_rows(upd, ms, w_new, w_mixed)
+            opt_state_new = _mask_update_rows(upd, ms, opt_state_new,
+                                              state.opt_state)
 
     # ---- paper metrics: reduce in single-device order --------------------
-    def global_order(x_local):
-        # (ms,) -> (m,) in *global* device order: the all-gather lands in
-        # shard-major (permuted) order, inv_perm maps device id -> position
-        return jax.lax.all_gather(x_local, axis_name).reshape(-1)[inv_perm]
+    with jax.named_scope("efhc.stats"):
+        def global_order(x_local):
+            # (ms,) -> (m,) in *global* device order: the all-gather lands in
+            # shard-major (permuted) order, inv_perm maps device id -> position
+            return jax.lax.all_gather(x_local, axis_name).reshape(-1)[inv_perm]
 
-    deg = deg_i.astype(jnp.float32)
-    used = used_i.astype(jnp.float32)
-    frac = jnp.where(deg > 0, used / jnp.maximum(deg, 1.0), 0.0)
-    tx_time = jnp.mean(global_order(frac * model_dim / bw_live))
-    capacity = jnp.sum(global_order(deg * bw_live))
-    util = (jnp.sum(global_order(used * model_dim))
-            / jnp.maximum(capacity, 1e-12))
+        deg = deg_i.astype(jnp.float32)
+        used = used_i.astype(jnp.float32)
+        frac = jnp.where(deg > 0, used / jnp.maximum(deg, 1.0), 0.0)
+        tx_time = jnp.mean(global_order(frac * model_dim / bw_live))
+        capacity = jnp.sum(global_order(deg * bw_live))
+        util = (jnp.sum(global_order(used * model_dim))
+                / jnp.maximum(capacity, 1e-12))
 
-    w_new_flat = _flatten_stack(w_new)
-    col_mean = jax.lax.psum(w_new_flat.sum(axis=0), axis_name) / m
-    consensus_err = jax.lax.psum(jnp.sum((w_new_flat - col_mean) ** 2),
-                                 axis_name)
+        w_new_flat = _flatten_stack(w_new)
+        col_mean = jax.lax.psum(w_new_flat.sum(axis=0), axis_name) / m
+        consensus_err = jax.lax.psum(jnp.sum((w_new_flat - col_mean) ** 2),
+                                     axis_name)
 
-    if dyn:
-        n_bytes = float(accounting.model_bytes(model_dim))
-        res_new = resources_mod.ResourceState(
-            bw=bw_live, budget=res.budget - n_bytes * v.astype(jnp.float32),
-            up=up, key=r_key)
-        down_count = jax.lax.psum(jnp.sum(~up).astype(jnp.int32), axis_name)
-        exhausted_count = jax.lax.psum(
-            jnp.sum(exhausted).astype(jnp.int32), axis_name)
-    else:
-        res_new = state.resources
-        down_count = jnp.zeros((), jnp.int32)
-        exhausted_count = jnp.zeros((), jnp.int32)
+        if dyn:
+            n_bytes = float(accounting.model_bytes(model_dim))
+            res_new = resources_mod.ResourceState(
+                bw=bw_live, budget=res.budget - n_bytes * v.astype(jnp.float32),
+                up=up, key=r_key)
+            down_count = jax.lax.psum(jnp.sum(~up).astype(jnp.int32), axis_name)
+            exhausted_count = jax.lax.psum(
+                jnp.sum(exhausted).astype(jnp.int32), axis_name)
+        else:
+            res_new = state.resources
+            down_count = jnp.zeros((), jnp.int32)
+            exhausted_count = jnp.zeros((), jnp.int32)
 
-    if fdyn:
-        f_new = faults_mod.FaultState(crashed=crashed, staleness=staleness,
-                                      cluster_down=cluster_down, key=f_key)
-        fault_down_count = jax.lax.psum(jnp.sum(~f_up).astype(jnp.int32),
-                                        axis_name)
-        stale_max = jax.lax.pmax(jnp.max(staleness), axis_name)
-    else:
-        f_new = state.faults
-        fault_down_count = jnp.zeros((), jnp.int32)
-        stale_max = jnp.zeros((), jnp.int32)
+        if fdyn:
+            f_new = faults_mod.FaultState(crashed=crashed, staleness=staleness,
+                                          cluster_down=cluster_down, key=f_key)
+            fault_down_count = jax.lax.psum(jnp.sum(~f_up).astype(jnp.int32),
+                                            axis_name)
+            stale_max = jax.lax.pmax(jnp.max(staleness), axis_name)
+        else:
+            f_new = state.faults
+            fault_down_count = jnp.zeros((), jnp.int32)
+            stale_max = jnp.zeros((), jnp.int32)
 
     new_state = EFHCState(
         w=w_new, w_hat=w_hat_new, k=state.k + 1, prev_adj=adj_ell,

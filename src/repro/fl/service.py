@@ -37,6 +37,7 @@ model server answers inference traffic:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import OrderedDict, deque
 from typing import Callable, Sequence
@@ -612,17 +613,48 @@ class ScenarioService:
         t_start = time.perf_counter()
         launch_id = self._stats.launches
         self._stats.launches += 1
-
-        ds = self._stager.provider(spec0)
-        graph = self._stager.graph(spec0)
-        eval_fn = self._stager.eval_fn(spec0, ds)
         cells = [(p, s) for p in group for s in p.spec.seeds]
-        self._stats.cells += len(cells)
+        # host spans on the profiler's clock; one launch's spans share its id
+        span = functools.partial(jax.profiler.TraceAnnotation,
+                                 launch_id=launch_id, cells=len(cells))
 
-        if spec0.mix_impl == "sharded":
+        with span("service.stage"):
+            ds = self._stager.provider(spec0)
+            graph = self._stager.graph(spec0)
+            eval_fn = self._stager.eval_fn(spec0, ds)
+            self._stats.cells += len(cells)
+            staged = (None if spec0.mix_impl == "sharded" else
+                      self._stage_grid(spec0, cells, ds, graph, eval_fn))
+        if staged is None:
             return self._launch_serial(group, cells, ds, graph, eval_fn,
-                                       t_start, launch_id)
+                                       t_start, launch_id, span)
+        eng, model_dim, engine_hit, pol, seeds, idx = staged
+        t_staged = time.perf_counter()
 
+        with span("service.launch"):
+            prog_key = (group[0].sig, len(pol))
+            program_hit = prog_key in self._seen_programs
+            self._seen_programs.add(prog_key)
+            self._stats.program_hits += int(program_hit)
+            self._stats.program_misses += int(not program_hit)
+            out = self._grid_for(eng)(pol, seeds, idx)
+        with span("service.fetch"):
+            host = jax.device_get(out)
+        t_done = time.perf_counter()
+
+        with span("service.report"):
+            results = [simulator._result_from_device(
+                jax.tree.map(lambda a: a[i], host), model_dim, spec0.trace)
+                for i in range(len(cells))]
+            return self._reports(group, cells, results, t_start=t_start,
+                                 stage_s=t_staged - t_start,
+                                 run_s=t_done - t_staged, launch_id=launch_id,
+                                 engine_hit=engine_hit,
+                                 program_hit=program_hit)
+
+    def _stage_grid(self, spec0, cells, ds, graph, eval_fn):
+        """The launch's engine (cache hit or not) and its (policy, seed,
+        index) inputs, padded to the launch's bucket of cells."""
         before = simulator.engine_cache_stats()
         eng, model_dim = simulator._cached_engine(
             spec0.to_sim(), graph, T=spec0.iters,
@@ -641,41 +673,23 @@ class ScenarioService:
             self._stats.padded_cells += pad
             rep = lambda a: np.concatenate([a, np.repeat(a[:1], pad, 0)])
             pol, seeds, idx = rep(pol), rep(seeds), rep(idx)
-        t_staged = time.perf_counter()
-
-        prog_key = (group[0].sig, b)
-        program_hit = prog_key in self._seen_programs
-        self._seen_programs.add(prog_key)
-        self._stats.program_hits += int(program_hit)
-        self._stats.program_misses += int(not program_hit)
-
-        grid = self._grid_for(eng)
-        host = jax.device_get(grid(pol, seeds, idx))
-        t_done = time.perf_counter()
-
-        results = [simulator._result_from_device(
-            jax.tree.map(lambda a: a[i], host), model_dim, spec0.trace)
-            for i in range(n)]
-        return self._reports(group, cells, results, t_start=t_start,
-                             stage_s=t_staged - t_start,
-                             run_s=t_done - t_staged, launch_id=launch_id,
-                             engine_hit=engine_hit, program_hit=program_hit)
+        return eng, model_dim, engine_hit, pol, seeds, idx
 
     def _launch_serial(self, group, cells, ds, graph, eval_fn, t_start,
-                       launch_id) -> list[ScenarioReport]:
+                       launch_id, span) -> list[ScenarioReport]:
         before = simulator.engine_cache_stats()
-        results = []
-        for p, s in cells:
-            results.append(simulator.run(
+        with span("service.launch"):  # each run adds its own sim.* spans
+            results = [simulator.run(
                 p.spec.to_sim(seed=s), graph, p.spec.batches(s, ds),
-                eval_fn, eval_every=p.spec.eval_every))
+                eval_fn, eval_every=p.spec.eval_every) for p, s in cells]
         after = simulator.engine_cache_stats()
         t_done = time.perf_counter()
-        return self._reports(group, cells, results, t_start=t_start,
-                             stage_s=0.0, run_s=t_done - t_start,
-                             launch_id=launch_id,
-                             engine_hit=after.hits > before.hits,
-                             program_hit=after.misses == before.misses)
+        with span("service.report"):
+            return self._reports(group, cells, results, t_start=t_start,
+                                 stage_s=0.0, run_s=t_done - t_start,
+                                 launch_id=launch_id,
+                                 engine_hit=after.hits > before.hits,
+                                 program_hit=after.misses == before.misses)
 
     @staticmethod
     def _diverged(res: SimResult) -> bool:

@@ -125,29 +125,31 @@ def make_sharded_engine(
         def global_order(x_local):
             return jax.lax.all_gather(x_local, _AXIS).reshape(-1)[inv_perm]
 
-        # fleet-global RNG streams, sliced to the owned rows: identical
-        # per-device values at every shard count
-        bw = triggers.sample_bandwidths(k_bw, m, sim.b_mean, sim.sigma_n)
-        bw_l = bw[ctx.owned]
-        w0 = spec.init_rows(k_init, m, ctx.owned)
-        adj0 = graph.adjacency_ell_rows(0, ctx.nbr_gid, ctx.mask, ctx.owned)
-        # resource state: local rows, fleet-global stream key (replicated)
-        res0 = (resources_mod.init_state(rcfg, bw_l, k_res)
-                if rcfg is not None else None)
-        # fault state: local crash/staleness rows, fleet-global cluster
-        # bits + stream key (replicated on every shard)
-        f0 = (faults_mod.init_state(fcfg, fab, k_fault, rows=ctx.owned)
-              if fcfg is not None else None)
-        wd0 = (flow_mod.watchdog_init(ctx.nbr_loc.shape[0],
-                                      ctx.nbr_loc.shape[1])
-               if wcfg is not None else None)
-        state = efhc.init_state(w0, bw_l, adj0, k_state,
-                                opt_state=opt.init(w0), resources=res0,
-                                faults=f0, watchdog=wd0)
+        with jax.named_scope("efhc.init"):
+            # fleet-global RNG streams, sliced to the owned rows: identical
+            # per-device values at every shard count
+            bw = triggers.sample_bandwidths(k_bw, m, sim.b_mean, sim.sigma_n)
+            bw_l = bw[ctx.owned]
+            w0 = spec.init_rows(k_init, m, ctx.owned)
+            adj0 = graph.adjacency_ell_rows(0, ctx.nbr_gid, ctx.mask, ctx.owned)
+            # resource state: local rows, fleet-global stream key (replicated)
+            res0 = (resources_mod.init_state(rcfg, bw_l, k_res)
+                    if rcfg is not None else None)
+            # fault state: local crash/staleness rows, fleet-global cluster
+            # bits + stream key (replicated on every shard)
+            f0 = (faults_mod.init_state(fcfg, fab, k_fault, rows=ctx.owned)
+                  if fcfg is not None else None)
+            wd0 = (flow_mod.watchdog_init(ctx.nbr_loc.shape[0],
+                                          ctx.nbr_loc.shape[1])
+                   if wcfg is not None else None)
+            state = efhc.init_state(w0, bw_l, adj0, k_state,
+                                    opt_state=opt.init(w0), resources=res0,
+                                    faults=f0, watchdog=wd0)
 
         def one_step(st, per):
             ix, alpha = per  # ix: (ms, batch) dataset rows
-            batch = (x_all[ix], y_all[ix])
+            with jax.named_scope("efhc.event4"):
+                batch = (x_all[ix], y_all[ix])
             st, aux = efhc.step_sharded(
                 cfg, graph, ctx, st, grad_fn=grad_fn, batch=batch,
                 alpha_k=alpha, model_dim=model_dim, m=m, inv_perm=inv_perm,
@@ -164,8 +166,9 @@ def make_sharded_engine(
 
             # per-device accuracies, reduced in global order: matches the
             # single-device EvalFn.device (vmap + mean over all m)
-            return jnp.mean(global_order(jax.vmap(one)(st.w))).astype(
-                jnp.float32)
+            with jax.named_scope("efhc.eval"):
+                return jnp.mean(global_order(jax.vmap(one)(st.w))).astype(
+                    jnp.float32)
 
         def chunk_body(st, chunk):
             st, aux0 = one_step(st, jax.tree.map(lambda a: a[0], chunk))

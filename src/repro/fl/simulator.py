@@ -370,25 +370,26 @@ class _EngineCore:
 
     def init(self, seed) -> tuple[efhc.EFHCState, jax.Array]:
         """Initial carry + bandwidths for a run seed (pure, jit-able)."""
-        sim, graph = self.sim, self.graph
-        key = jax.random.PRNGKey(seed)
-        k_bw, k_init, k_state = jax.random.split(key, 3)
-        bw = triggers.sample_bandwidths(k_bw, self.m, sim.b_mean, sim.sigma_n)
-        w0 = self.spec.init_stack(k_init, self.m)
-        adj0 = (graph.adjacency_ell(0, self.nl) if self.sparse
-                else graph.adjacency(0))
-        res0 = (resources_mod.init_state(
-                    self.rcfg, bw, resources_mod.resource_key(key, self.rcfg))
-                if self.rcfg is not None else None)
-        f0 = (faults_mod.init_state(
-                  self.fcfg, self.fab, faults_mod.fault_key(key, self.fcfg))
-              if self.fcfg is not None else None)
-        wd0 = (flow_mod.watchdog_init(self.m, self.nl.idx.shape[1])
-               if self.wcfg is not None else None)
-        state = efhc.init_state(w0, bw, adj0, k_state,
-                                opt_state=self.opt.init(w0), resources=res0,
-                                faults=f0, watchdog=wd0)
-        return state, bw
+        with jax.named_scope("efhc.init"):
+            sim, graph = self.sim, self.graph
+            key = jax.random.PRNGKey(seed)
+            k_bw, k_init, k_state = jax.random.split(key, 3)
+            bw = triggers.sample_bandwidths(k_bw, self.m, sim.b_mean, sim.sigma_n)
+            w0 = self.spec.init_stack(k_init, self.m)
+            adj0 = (graph.adjacency_ell(0, self.nl) if self.sparse
+                    else graph.adjacency(0))
+            res0 = (resources_mod.init_state(
+                        self.rcfg, bw, resources_mod.resource_key(key, self.rcfg))
+                    if self.rcfg is not None else None)
+            f0 = (faults_mod.init_state(
+                      self.fcfg, self.fab, faults_mod.fault_key(key, self.fcfg))
+                  if self.fcfg is not None else None)
+            wd0 = (flow_mod.watchdog_init(self.m, self.nl.idx.shape[1])
+                   if self.wcfg is not None else None)
+            state = efhc.init_state(w0, bw, adj0, k_state,
+                                    opt_state=self.opt.init(w0), resources=res0,
+                                    faults=f0, watchdog=wd0)
+            return state, bw
 
     def trace_ys(self, aux: efhc.StepAux) -> dict:
         """Per-iteration scan ys: the (m, m) float P matrix is never
@@ -398,21 +399,22 @@ class _EngineCore:
         sums come from StepAux directly, so under trace="summary" the
         ys never touch aux.comm/aux.adj at all -- which is what lets
         the sparse mix impls dead-code-eliminate the dense scatters."""
-        ys = {"loss": aux.loss, "tx_time": aux.tx_time, "util": aux.util,
-              "v": aux.v, "consensus_err": aux.consensus_err,
-              "comm_count": aux.comm_count, "deg": aux.deg,
-              "down_count": aux.down_count,
-              "exhausted_count": aux.exhausted_count,
-              "fault_down_count": aux.fault_down_count,
-              "stale_max": aux.stale_max,
-              "window_connected": aux.window_connected,
-              "window_needed": aux.window_needed}
-        if self.trace == "full":
-            ys["comm"], ys["adj"] = aux.comm, aux.adj
-        elif self.trace == "packed":
-            ys["comm"] = trace_mod.pack_links(aux.comm)
-            ys["adj"] = trace_mod.pack_links(aux.adj)
-        return ys
+        with jax.named_scope("efhc.ys"):
+            ys = {"loss": aux.loss, "tx_time": aux.tx_time, "util": aux.util,
+                  "v": aux.v, "consensus_err": aux.consensus_err,
+                  "comm_count": aux.comm_count, "deg": aux.deg,
+                  "down_count": aux.down_count,
+                  "exhausted_count": aux.exhausted_count,
+                  "fault_down_count": aux.fault_down_count,
+                  "stale_max": aux.stale_max,
+                  "window_connected": aux.window_connected,
+                  "window_needed": aux.window_needed}
+            if self.trace == "full":
+                ys["comm"], ys["adj"] = aux.comm, aux.adj
+            elif self.trace == "packed":
+                ys["comm"] = trace_mod.pack_links(aux.comm)
+                ys["adj"] = trace_mod.pack_links(aux.adj)
+            return ys
 
     def span(self, policy_idx, state: efhc.EFHCState, idx, alphas, *,
              final: bool):
@@ -425,7 +427,8 @@ class _EngineCore:
 
         def one_step(st, per):
             ix, alpha = per  # ix: (m, batch) dataset rows for this iteration
-            batch = (self.x_all[ix], self.y_all[ix])
+            with jax.named_scope("efhc.event4"):
+                batch = (self.x_all[ix], self.y_all[ix])
             st, aux = efhc.step(self.cfg, self.graph, st,
                                 grad_fn=self.spec.grad_fn, batch=batch,
                                 alpha_k=alpha, model_dim=self.model_dim,
@@ -436,7 +439,8 @@ class _EngineCore:
         def eval_acc(st):
             if self.eval_dev is None:
                 return jnp.asarray(0.0, jnp.float32)
-            return self.eval_dev(st.w).astype(jnp.float32)
+            with jax.named_scope("efhc.eval"):
+                return self.eval_dev(st.w).astype(jnp.float32)
 
         def chunk_body(st, chunk):
             # eval after the chunk's first step = iterations 0, E, 2E, ...
@@ -689,10 +693,15 @@ def run(
         eng, model_dim = _cached_engine(
             sim, graph, T=sim.iters, eval_every=eval_every,
             x=batches.x, y=batches.y, eval_fn=eval_fn)
-        idx = batches.stage(sim.iters)
-        out = eng(triggers.policy_index(sim.policy),
-                  jnp.asarray(sim.seed, jnp.int32), jnp.asarray(idx))
-        return _result_from_device(out, model_dim, sim.trace)
+        # host spans on the profiler's clock, beside the device's ops
+        args = {"m": sim.m, "T": sim.iters}
+        with jax.profiler.TraceAnnotation("sim.stage", **args):
+            idx = batches.stage(sim.iters)
+        with jax.profiler.TraceAnnotation("sim.launch", **args):
+            out = eng(triggers.policy_index(sim.policy),
+                      jnp.asarray(sim.seed, jnp.int32), jnp.asarray(idx))
+        with jax.profiler.TraceAnnotation("sim.fetch", **args):
+            return _result_from_device(out, model_dim, sim.trace)
     if sim.mix_impl == "sharded":
         raise ValueError(
             "mix_impl='sharded' runs only under engine='scan' with an "
