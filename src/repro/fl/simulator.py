@@ -868,7 +868,10 @@ def run_checkpointed(
     Batch staging stays deterministic across processes:
     ``FederatedBatches.stage(T)`` draws from the construction-seeded rng,
     so a fresh ``batches`` object in the resuming process stages the
-    identical (T, m, batch) index tensor.
+    identical (T, m, batch) index tensor.  Its vectorised draw (one
+    ``rng.integers`` call over all devices per chunk of iterations) is
+    bit-exact to the per-device ``rng.choice`` loop, so this holds
+    whichever process stages.
     """
     from repro.checkpoint import msgpack_ckpt
 
