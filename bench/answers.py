@@ -1,10 +1,12 @@
-"""What the drivers share: the cell's data and fabric, made from the
+"""What the EF-HC drivers share: the cell's data and fabric, made from the
 configuration, the adapter from the configuration's words to the
-program's knobs, and the answers a window produced."""
+program's knobs, the answers a window produced, and the plain reference
+that replays them."""
 from __future__ import annotations
 
 import dataclasses
 
+import jax.numpy as jnp
 import numpy as np
 
 from bench import gen
@@ -86,3 +88,36 @@ def scenario(config: dict, traffic: dict, edges) -> ref_efhc.Scenario:
 def draw_seed(rng: np.random.Generator) -> int:
     """A program seed: a whole number the program holds in int32."""
     return int(rng.integers(0, 2**30))
+
+
+class Replay:
+    """The plain reference of one scenario (``bench.reference.efhc``),
+    staging each answer's minibatches from its ``sample_seed``.
+    ``precision="highest"`` computes the models' matmuls with full-precision
+    operands, as the program does under that default matmul precision."""
+
+    def __init__(self, sc: ref_efhc.Scenario, data: Data, dtype=jnp.float32,
+                 precision: str | None = None):
+        if precision == "highest":
+            sc = dataclasses.replace(sc, matmul_operands="float32")
+        elif precision is not None:
+            raise ValueError(f"precision {precision!r}: only 'highest'")
+        self.sc, self.parts = sc, data.parts
+        self.ref = ref_efhc.Reference(sc, data.x, data.y, data.x_test,
+                                      data.y_test, dtype=dtype)
+
+    def _idx(self, sample_seed: int) -> np.ndarray:
+        return gen.stage(self.parts, self.sc.batch, sample_seed, self.sc.T)
+
+    def replay(self, answer: Answer) -> dict:
+        """The reference's trajectory following the answer's broadcast
+        decisions: the channels ``check.compare`` reads, ``charged``
+        included."""
+        return self.ref.run(answer.seed, answer.policy,
+                            self._idx(answer.sample_seed),
+                            forced_v=answer.out["v"])
+
+    def answer(self, seed: int, policy: str, sample_seed: int) -> Answer:
+        """The reference in the program's place, on its own decisions."""
+        return Answer(seed, policy, sample_seed,
+                      self.ref.run(seed, policy, self._idx(sample_seed)))

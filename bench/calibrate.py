@@ -5,9 +5,10 @@
 
 In one process, at the cell's own size and load: set-up as a run makes
 it, then closed-loop calls until ``--answers`` answers are sampled (as
-many per call as a run checks), each compared with the reference; then
-``--control`` of those answers recomputed by the control, the reference
-itself at bfloat16, in the program's place and compared the same way.
+many per call as a run checks), each compared with the cell's reference
+(``Cell.reference``); then ``--control`` of those answers recomputed by the
+control, the reference itself at bfloat16, in the program's place and
+compared the same way.
 Prints one JSON object: every number's readings for the program and the
 control, and per answer each number's curve over the horizon.
 
@@ -50,14 +51,12 @@ def _short(reading: dict) -> dict:
 def readings(workload: str, seed: int, n_answers: int, n_control: int,
              manifest, log=print, precision: str | None = None) -> dict:
     import contextlib
-    import dataclasses
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import check, gen
-    from bench.reference import efhc as ref_efhc
+    from bench import check
 
     wl = manifest.workload(workload)
     config, traffic = manifest.config(wl["config"]), manifest.traffic(wl["traffic"])
@@ -77,28 +76,23 @@ def readings(workload: str, seed: int, n_answers: int, n_control: int,
             sample += [got[i] for i in sorted(pick)]
             log(f"call {time.perf_counter() - t0:.3f}s, {len(sample)} answers")
     sample = sample[:n_answers]
-    sc, data = cell.reference_inputs()
+    ref = cell.reference(precision=precision)
+    low = cell.reference(dtype=jnp.bfloat16) if n_control else None
     del cell
-    if precision is not None:
-        sc = dataclasses.replace(sc, matmul_operands="float32")
-    ref = ref_efhc.Reference(sc, data.x, data.y, data.x_test, data.y_test)
-    low = ref_efhc.Reference(sc, data.x, data.y, data.x_test, data.y_test,
-                             dtype=jnp.bfloat16)
     out = {"workload": workload, "seed": seed, "precision": precision,
            "program": [], "control": []}
     for i, a in enumerate(sample):
-        idx = gen.stage(data.parts, sc.batch, a.sample_seed, sc.T)
-        r = ref.run(a.seed, a.policy, idx, forced_v=a.out["v"])
+        r = ref.replay(a)
         out["program"].append({"seed": a.seed, "policy": a.policy,
                                **check.compare(a.out, r, iters),
                                "curve": _curve(a.out, r)})
         log(f"program {_short(out['program'][-1])}")
         if i < n_control:
-            ctl = low.run(a.seed, a.policy, idx)
-            r = ref.run(a.seed, a.policy, idx, forced_v=ctl["v"])
+            ctl = low.answer(a.seed, a.policy, a.sample_seed)
+            r = ref.replay(ctl)
             out["control"].append({"seed": a.seed, "policy": a.policy,
-                                   **check.compare(ctl, r, iters),
-                                   "curve": _curve(ctl, r)})
+                                   **check.compare(ctl.out, r, iters),
+                                   "curve": _curve(ctl.out, r)})
             log(f"control {_short(out['control'][-1])}")
     for side in ("program", "control"):
         out[f"{side}_max"] = check.worst(out[side]) if out[side] else {}

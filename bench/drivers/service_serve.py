@@ -11,9 +11,10 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from bench import answers, work
+from bench import answers, scopes, work
 
 
 class Cell:
@@ -81,6 +82,10 @@ class Cell:
         one = work.iteration_work(self.config, self.traffic, float(links / cells))
         return {k: v * cells for k, v in one.items()}
 
-    def reference_inputs(self):
-        return (answers.scenario(self.config, self.traffic,
-                                 answers.fabric(self.config)), self.data)
+    def op_scopes(self) -> dict:
+        return scopes.live_op_scopes("engine")
+
+    def reference(self, dtype=jnp.float32, precision=None) -> answers.Replay:
+        return answers.Replay(answers.scenario(self.config, self.traffic,
+                                               answers.fabric(self.config)),
+                              self.data, dtype, precision)

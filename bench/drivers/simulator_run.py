@@ -3,28 +3,18 @@
 Each call simulates the configured fleet for T iterations from a fresh run
 seed and sampler seed (both traced by the engine, so no call recompiles),
 with the policy the traffic names, through the entry users call.  The
-sampler is the program's ``FederatedBatches``; its ``stage`` runs inside a
-``bench.stage`` host span so the trace can name the host time it takes.
+sampler is the program's ``FederatedBatches``, whose staging the program's
+``sim.stage`` span names in the trace.
 """
 from __future__ import annotations
 
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from bench import answers, work
-
-
-def _spanned_batches():
-    from repro.data.loader import FederatedBatches
-
-    class SpannedBatches(FederatedBatches):
-        def stage(self, T):
-            with jax.profiler.TraceAnnotation("bench.stage"):
-                return super().stage(T)
-
-    return SpannedBatches
+from bench import answers, scopes, work
 
 
 class Cell:
@@ -48,17 +38,17 @@ class Cell:
             mix_impl=answers.mix_impl(config), trace="summary")
         self.eval_fn = make_eval_fn(self.sim, self.data.x_test,
                                     self.data.y_test)
-        self._batches = _spanned_batches()
 
     def call(self) -> dict:
         """One simulation; returns {answers, dev_iters, scan_iters}."""
+        from repro.data.loader import FederatedBatches
         from repro.fl import simulator
 
         seed, sample_seed = (answers.draw_seed(self.rng),
                              answers.draw_seed(self.rng))
         d = self.data
-        batches = self._batches(d.x, d.y, d.parts, self.sim.batch,
-                                seed=sample_seed)
+        batches = FederatedBatches(d.x, d.y, d.parts, self.sim.batch,
+                                   seed=sample_seed)
         res = simulator.run(dataclasses.replace(self.sim, seed=seed),
                             self.graph, batches, self.eval_fn,
                             eval_every=self.traffic["eval_every"])
@@ -81,6 +71,10 @@ class Cell:
                          for call in calls for a in call["answers"]])
         return work.iteration_work(self.config, self.traffic, float(links))
 
-    def reference_inputs(self):
-        """(scenario, data) the reference replays answers with."""
-        return answers.scenario(self.config, self.traffic, self.edges), self.data
+    def op_scopes(self) -> dict:
+        return scopes.live_op_scopes("engine")
+
+    def reference(self, dtype=jnp.float32, precision=None) -> answers.Replay:
+        return answers.Replay(answers.scenario(self.config, self.traffic,
+                                               self.edges),
+                              self.data, dtype, precision)

@@ -11,10 +11,28 @@ benchmark's directory, found by the name:
     limits/<workload>.json    the limits of the comparison that decides
                               ``correct``, with the readings behind them
 
+A driver's ``Cell(config, traffic, rng)`` holds the cell's data and
+program and gives:
+
+    call()              one closed-loop call: {"answers", "dev_iters",
+                        "scan_iters", ...}; each answer has ``out``, the
+                        channels ``bench.check.compare`` reads
+    counters()          the program's counters, logged after the window
+    iteration_work(calls)  operations and bytes of one scan iteration
+    op_scopes()         after the window, instruction name -> scopes of the
+                        executables it ran (``bench.scopes.live_op_scopes``)
+    reference(dtype=jnp.float32, precision=None)
+                        the cell's plain reference: ``replay(answer)`` gives
+                        the channels ``check.compare`` reads, following the
+                        answer's decisions; ``answer(seed, policy,
+                        sample_seed)`` puts the reference in the program's
+                        place (at ``dtype=jnp.bfloat16``, the control)
+
 A run: set-up (data, fabric, program, one warm-up call at the window's
 shapes), the measured window of closed-loop calls, the device's memory
-peak, the trace reduction (``--trace 1``), then the check of a sample of
-the window's answers against the plain reference.
+peak, the trace reduction and its attribution to the program's scopes and
+spans (``--trace 1``), then the check of a sample of the window's answers
+against the cell's reference.
 """
 from __future__ import annotations
 
@@ -28,7 +46,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import check, gen
+from bench import check
 
 BENCH_DIR = Path(__file__).resolve().parent
 # Each engine carries its training set as a constant, so the fleet's
@@ -130,6 +148,7 @@ class Context:
     trace: object | None  # bench.trace.Reduced
     work: dict  # operations and bytes of one scan iteration
     peaks: dict  # the device's published peaks
+    scopes: object | None = None  # bench.scopes.Attributed, traced runs
 
 
 class _CompileCounter:
@@ -181,14 +200,15 @@ def _finite(x: float):
 
 def run(name: str, seed: int, seconds: float, traced: bool, *,
         manifest: Manifest, devices, device_kind: str, t0: float,
-        log=print) -> dict:
+        log=print, on_context=None) -> dict:
     """One run of one cell; returns the result object (without the
-    device fields the caller adds)."""
+    device fields the caller adds).  ``on_context`` is handed the metric
+    readers' Context."""
     import jax
 
     from bench import peaks as peaks_mod
+    from bench import scopes as scopes_mod
     from bench import trace as trace_mod
-    from bench.reference import efhc as ref_efhc
 
     wl = manifest.workload(name)
     config, traffic = manifest.config(wl["config"]), manifest.traffic(wl["traffic"])
@@ -227,19 +247,27 @@ def run(name: str, seed: int, seconds: float, traced: bool, *,
         f"{json.dumps(cell.counters())}")
     peak = _memory_peaks(devices, log)
 
-    reduced = None
+    reduced = attributed = None
     if traced:
-        reduced = trace_mod.reduce(trace_mod.load(tdir, len(devices)))
+        t_trace = time.perf_counter()
+        tr = trace_mod.load(tdir, len(devices))
+        reduced = trace_mod.reduce(tr)
+        attributed = scopes_mod.attribute(tr, cell.op_scopes())
         shutil.rmtree(tdir, ignore_errors=True)
+        log(f"trace read and attributed {time.perf_counter() - t_trace:.3f}s, "
+            f"{100 * attributed.covered:.3f}% of busy time scoped")
     ctx = Context(setup_s=setup_s, window_s=window_s, calls=calls,
                   peak_bytes=peak, trace=reduced,
                   work=cell.iteration_work(calls),
-                  peaks=peaks_mod.peaks(device_kind) if device_kind else {})
+                  peaks=peaks_mod.peaks(device_kind) if device_kind else {},
+                  scopes=attributed)
     metrics = {}
     for m in manifest.metrics(name, traced):
         v = manifest.reader(m["name"])(ctx)
         if v is not None:
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
+    if on_context is not None:
+        on_context(ctx)
 
     # the check: a sample of the window's answers against the reference
     done = [a for c in calls for a in c["answers"]]
@@ -248,13 +276,8 @@ def run(name: str, seed: int, seconds: float, traced: bool, *,
     sample = [done[i] for i in sorted(pick)]
     del calls, ctx
     t_check = time.perf_counter()
-    sc, data = cell.reference_inputs()
-    ref = ref_efhc.Reference(sc, data.x, data.y, data.x_test, data.y_test)
-    readings = []
-    for a in sample:
-        idx = gen.stage(data.parts, sc.batch, a.sample_seed, sc.T)
-        r = ref.run(a.seed, a.policy, idx, forced_v=a.out["v"])
-        readings.append(check.compare(a.out, r, iters))
+    ref = cell.reference()
+    readings = [check.compare(a.out, ref.replay(a), iters) for a in sample]
     judged = check.judge(check.worst(readings), limits)
     log(f"check of {len(sample)} answers {time.perf_counter() - t_check:.3f}s")
     failed = sum(not (np.isfinite(a.out["loss"]).all()

@@ -14,36 +14,34 @@ for p in (str(ROOT / "src"), str(ROOT)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-# each cell shrunk to seconds on a CPU: fewer devices, iterations and
-# samples; every width (dim, classes, conv channels) as configured.  The
-# CPU computes float32 matmuls in full at its default precision, where the
-# TPU rounds their operands to bfloat16, so the reference models that.
-CPU = {"matmul_operands": "float32"}
-TINY = {
-    "fleet16k-ell": ({"m": 48, "n_train": 192, "n_test": 40, **CPU},
-                     {"T": 6, "eval_every": 4}),
-    "paper-lenet-grid": ({"n_train": 200, "n_test": 40, **CPU},
-                         {"T": 5, "eval_every": 3, "seeds_per_request": 1,
-                          "max_cells": 4}),
-}
+BENCHMARK = json.loads((ROOT / "BENCHMARK.json").read_text())
+CELLS = [wl["name"] for wl in BENCHMARK["workloads"]]
+# tiny/<workload>.json: each cell's size on a CPU, as overrides of its
+# configuration and traffic mix
+TINY_DIR = Path(__file__).resolve().parent / "tiny"
 
 
-def make_tiny(dest: Path) -> "object":
-    """A copy of the benchmark under ``dest`` with every cell at TINY size
-    and the committed limits; returns its Manifest."""
+def make_tiny(dest: Path, data: dict = BENCHMARK, src: Path | None = None,
+              tiny: Path = TINY_DIR) -> "object":
+    """A copy under ``dest`` of the benchmark ``data`` describes, its files
+    from ``src`` (the committed benchmark's by default), with every cell at
+    the size ``tiny`` gives it and the committed limits; returns its
+    Manifest."""
     from bench.harness import BENCH_DIR, Manifest
 
-    data = json.loads((ROOT / "BENCHMARK.json").read_text())
-    for d in ("drivers", "metrics", "limits"):
-        shutil.copytree(BENCH_DIR / d, dest / d)
+    src = BENCH_DIR if src is None else src
+    for d in src.iterdir():
+        if d.is_dir() and d.name not in ("configs", "traffic", "__pycache__"):
+            shutil.copytree(d, dest / d.name,
+                            ignore=shutil.ignore_patterns("__pycache__"))
     for d in ("configs", "traffic"):
         (dest / d).mkdir()
     for wl in data["workloads"]:
-        cfg_over, tr_over = TINY[wl["name"]]
-        cfg = json.loads((BENCH_DIR / "configs" / f"{wl['config']}.json").read_text())
-        tr = json.loads((BENCH_DIR / "traffic" / f"{wl['traffic']}.json").read_text())
-        cfg.update(cfg_over)
-        tr.update(tr_over)
+        size = json.loads((tiny / f"{wl['name']}.json").read_text())
+        cfg = json.loads((src / "configs" / f"{wl['config']}.json").read_text())
+        tr = json.loads((src / "traffic" / f"{wl['traffic']}.json").read_text())
+        cfg.update(size["config"])
+        tr.update(size["traffic"])
         (dest / "configs" / f"{wl['config']}.json").write_text(json.dumps(cfg))
         (dest / "traffic" / f"{wl['traffic']}.json").write_text(json.dumps(tr))
     return Manifest(data, dest)

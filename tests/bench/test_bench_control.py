@@ -6,10 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, gen
-from bench.reference import efhc as ref_efhc
-
-CELLS = ["fleet16k-ell", "paper-lenet-grid"]
+from bench import check
+from conftest import CELLS
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -18,17 +16,13 @@ def test_control_is_not_correct(tiny, workload):
     config, traffic = tiny.config(wl["config"]), tiny.traffic(wl["traffic"])
     cell = tiny.driver(traffic["driver"]).Cell(config, traffic,
                                                np.random.default_rng(5))
-    sc, data = cell.reference_inputs()
-    ref = ref_efhc.Reference(sc, data.x, data.y, data.x_test, data.y_test)
-    low = ref_efhc.Reference(sc, data.x, data.y, data.x_test, data.y_test,
-                             dtype=jnp.bfloat16)
+    ref, low = cell.reference(), cell.reference(dtype=jnp.bfloat16)
     limits = tiny.limits(workload)
     for seed, policy in ((11, "efhc"), (12, "global"), (13, "gossip")):
-        idx = gen.stage(data.parts, sc.batch, seed + 2, sc.T)
-        ctl = low.run(seed, policy, idx)
+        ctl = low.answer(seed, policy, seed + 2)
         judged = check.judge(check.compare(
-            ctl, ref.run(seed, policy, idx, forced_v=ctl["v"]),
-            tiny.compared_iterations(workload)), limits)
+            ctl.out, ref.replay(ctl), tiny.compared_iterations(workload)),
+            limits)
         assert not all(j["ok"] for j in judged), judged
 
 

@@ -6,9 +6,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from conftest import run_tiny
-
-CELLS = ["fleet16k-ell", "paper-lenet-grid"]
+from conftest import CELLS, run_tiny
 
 
 @pytest.mark.parametrize("workload", CELLS)

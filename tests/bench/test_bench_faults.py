@@ -4,9 +4,7 @@ from __future__ import annotations
 
 import pytest
 
-from conftest import run_tiny
-
-CELLS = ["fleet16k-ell", "paper-lenet-grid"]
+from conftest import CELLS, run_tiny
 
 
 def _unchanged_state():

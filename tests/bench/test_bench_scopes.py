@@ -1,14 +1,17 @@
 """Attribution of a traced window to the program's named scopes and host
-spans (``bench.scopes``), on synthetic traces, a recorded one and tiny
-runs of the benchmark's cells."""
+spans (``bench.scopes``) and the per-layer metrics that read it, on
+synthetic traces, executables compiled here and tiny runs of the
+benchmark's cells."""
 from __future__ import annotations
 
 import pytest
 
 from bench import scopes as sc
 from bench import trace as tr
+from bench.harness import BENCH_DIR, Context, Manifest
 
 op = span = tr.Event
+READERS = Manifest.load(BENCH_DIR.parent / "BENCHMARK.json")
 
 
 @pytest.mark.parametrize("op_name,scope", [
@@ -21,10 +24,24 @@ op = span = tr.Event
     ("reduce_sum", None),
 ])
 def test_scope_is_the_innermost_efhc_component(op_name, scope):
-    assert sc.scope_of(op_name) == scope
+    assert sc.scopes_of(op_name) == (scope,)
 
 
-def test_hlo_text_maps_instructions_to_scopes(tmp_path):
+@pytest.mark.parametrize("op_name,scopes", [
+    ("jit(engine)/while/body/efhc.event4/mla.attn/dot_general",
+     ("efhc.event4", "mla.attn", None)),
+    ("jit(engine)/while/body/transpose(jvp(efhc.event4))/transpose(jvp(moe.experts))/mla.x/dot",
+     ("efhc.event4", "mla.x", "moe.experts")),
+    ("jit(engine)/moe.router/efhc.event2/sub", ("efhc.event2", None, "moe.router")),
+    ("jit(engine)/notefhc.event3/mla_attn/add", (None, None, None)),
+])
+def test_scopes_per_prefix(op_name, scopes):
+    """One scope per stated prefix, each the innermost of its own prefix,
+    whichever way the program nests them."""
+    assert sc.scopes_of(op_name, ("efhc.", "mla.", "moe.")) == scopes
+
+
+def test_hlo_text_maps_instructions_to_scopes():
     text = "\n".join([
         'HloModule jit_engine, entry_computation_layout={()->f32[]}',
         '  %p = f32[8]{0} parameter(0), metadata={op_name="w"}',
@@ -35,13 +52,35 @@ def test_hlo_text_maps_instructions_to_scopes(tmp_path):
         'metadata={op_name="jit(engine)/while/body/efhc.eval/reduce_window_sum"}',
         '  %copy.7 = f32[8]{0} copy(%p)',
     ])
-    want = {"fusion.364": "efhc.event3", "reduce-window.152": "efhc.eval"}
+    want = {"fusion.364": ("efhc.event3",), "reduce-window.152": ("efhc.eval",)}
     assert sc.hlo_scopes(text) == want
-    # an XLA dump directory: only the optimized modules name the ops run
-    (tmp_path / "module_0007.jit_engine.tpu_after_optimizations.txt").write_text(text)
-    (tmp_path / "module_0007.jit_engine.before_optimizations.txt").write_text(
-        '  %fusion.1 = f32[] add(%a, %b), metadata={op_name="efhc.event1/add"}')
-    assert sc.hlo_dir_scopes(str(tmp_path)) == want
+
+
+def _compiled_engine(scope: str):
+    import jax
+    import jax.numpy as jnp
+
+    def probe_engine(a):
+        with jax.named_scope(scope):
+            with jax.named_scope("mla.attn"):
+                return jnp.sin(a) * 3.0
+
+    f = jax.jit(probe_engine)
+    f(jnp.ones((8,))).block_until_ready()
+    return f
+
+
+def test_live_executables_map_the_engine_ops():
+    """The compiled text of the executables alive in the process names the
+    ops the trace names; an instruction that two such executables scope
+    differently is left out (the module names here are this test's own)."""
+    keep = [_compiled_engine("efhc.event3")]
+    got = sc.live_op_scopes("probe_engine", ("efhc.", "mla."))
+    assert ("efhc.event3", "mla.attn") in set(got.values())
+    keep.append(_compiled_engine("efhc.event2"))
+    both = sc.live_op_scopes("probe_engine", ("efhc.", "mla."))
+    assert not {op for op, s in got.items() if s == ("efhc.event3", "mla.attn")} & set(both)
+    assert not sc.live_op_scopes("no such module")
 
 
 def window_trace():
@@ -62,10 +101,16 @@ def window_trace():
 
 
 # the scopes the compiled HLO's metadata gives; the while has none
-HLO_MAP = {"fusion.1": "efhc.event3", "fusion.2": "efhc.event4",
-           "reduce-window.1": "efhc.eval", "fusion.9": "efhc.event1",
-           "fusion.20": "efhc.stats", "fusion.21": "efhc.ys",
-           "fusion.22": "efhc.init", "fusion.23": "efhc.event2"}
+HLO_MAP = {"fusion.1": ("efhc.event3",), "fusion.2": ("efhc.event4",),
+           "reduce-window.1": ("efhc.eval",), "fusion.9": ("efhc.event1",),
+           "fusion.20": ("efhc.stats",), "fusion.21": ("efhc.ys",),
+           "fusion.22": ("efhc.init",), "fusion.23": ("efhc.event2",)}
+
+
+def context(t: tr.Trace, att, iters: int = 7) -> Context:
+    return Context(setup_s=1.0, window_s=att.window_s,
+                   calls=[{"scan_iters": iters}], peak_bytes=None,
+                   trace=tr.reduce(t), work={}, peaks={}, scopes=att)
 
 
 def test_scoped_self_time_and_unscoped_remainder():
@@ -91,23 +136,68 @@ def test_scopes_sum_to_step_device_ms():
                         op("fusion.23", 9.15, 9.2)]
     att = sc.attribute(t, HLO_MAP)
     iters = 7
-    got = sc.layer_metrics(att, iters)
+    ctx = context(t, att, iters)
+    got = {m: READERS.reader(m)(ctx) for m in
+           ("graph_trigger_device_ms", "mix_device_ms", "local_step_device_ms",
+            "eval_device_ms", "step_device_ms")}
     rest = sum(att.scope_s[k] for k in ("efhc.stats", "efhc.ys", "efhc.init",
                                         sc.UNSCOPED))
     total = (got["graph_trigger_device_ms"] + got["mix_device_ms"]
              + got["local_step_device_ms"] + got["eval_device_ms"]
              + 1000.0 * rest / iters)
-    assert abs(total - 1000.0 * att.busy_s / iters) < 1e-9
+    assert abs(total - got["step_device_ms"]) < 1e-9
     assert got["graph_trigger_device_ms"] == pytest.approx(1000.0 * 1.05 / iters)
+
+
+@pytest.mark.parametrize("metric,seconds", [
+    ("graph_trigger_device_ms", 1.0), ("mix_device_ms", 2.0),
+    ("local_step_device_ms", 1.0), ("eval_device_ms", 0.5),
+])
+def test_device_ms_readers(metric, seconds):
+    t = window_trace()
+    ctx = context(t, sc.attribute(t, HLO_MAP), iters=4)
+    assert READERS.reader(metric)(ctx) == pytest.approx(1000.0 * seconds / 4)
+    # an attribution that names none of the metric's scopes gives nothing
+    assert READERS.reader(metric)(context(t, sc.attribute(t, {}), 4)) is None
+
+
+@pytest.mark.parametrize("metric", [
+    "graph_trigger_device_ms", "mix_device_ms", "local_step_device_ms",
+    "eval_device_ms", "host_stage_share"])
+def test_scope_readers_read_nothing_untraced(metric):
+    ctx = Context(setup_s=1.0, window_s=10.0, calls=[{"scan_iters": 7}],
+                  peak_bytes=None, trace=None, work={}, peaks={})
+    assert READERS.reader(metric)(ctx) is None
+
+
+def test_nested_prefix_keeps_the_event_totals():
+    """A model's scopes nested in the step's: an op inside ``efhc.event4``
+    counts toward Event 4 and toward its own layer; the step's scopes and
+    ``unscoped`` still sum to busy time, and a model op outside every step
+    scope stays unscoped there."""
+    t = window_trace()
+    t.device_ops[0].append(op("fusion.30", 9.2, 9.4))
+    nested = {**HLO_MAP, "fusion.2": ("efhc.event4", "mla.attn"),
+              "fusion.30": (None, "mla.experts")}
+    flat = {k: v[:1] for k, v in nested.items()}
+    got, want = sc.attribute(t, nested), sc.attribute(t, flat)
+    assert {k: v for k, v in got.scope_s.items() if not k.startswith("mla.")} \
+        == pytest.approx(want.scope_s)
+    assert got.scope_s["mla.attn"] == pytest.approx(got.scope_s["efhc.event4"])
+    assert got.scope_s["mla.experts"] == pytest.approx(0.2)
+    step = sum(v for k, v in got.scope_s.items() if k.startswith("efhc."))
+    assert step + got.scope_s[sc.UNSCOPED] == pytest.approx(got.busy_s)
+    assert got.covered == pytest.approx(want.covered)
 
 
 def test_host_stage_share_from_nested_program_spans():
     """A stage span counts once where spans nest in it or it repeats
     (the service's serial launches run ``sim.stage`` inside
     ``service.launch``); the benchmark's own spans count for nothing."""
-    att = sc.attribute(window_trace(), HLO_MAP)
+    t = window_trace()
+    att = sc.attribute(t, HLO_MAP)
     assert att.stage_s == pytest.approx(3.0)
-    assert sc.layer_metrics(att, 7)["host_stage_share"] == pytest.approx(30.0)
+    assert READERS.reader("host_stage_share")(context(t, att)) == pytest.approx(30.0)
     assert att.span_s == pytest.approx({"sim.stage": 3.0, "sim.launch": 0.3,
                                         "sim.fetch": 6.1})
     nested = tr.Trace(device_ops=[[]], host_spans=[
@@ -137,8 +227,9 @@ def test_no_call_span_is_an_error():
 ])
 def test_tiny_cell_attributed_run(tiny, workload, spans):
     """A traced run of each cell through the harness, attributed: the
-    program's spans name the window's host time, and the run's own result
-    is the harness's."""
+    program's spans name the window's host time, the run's own result is
+    the harness's, and the staging share is among its per-layer metrics
+    where the cell lists it."""
     import time
 
     import jax
@@ -150,5 +241,7 @@ def test_tiny_cell_attributed_run(tiny, workload, spans):
     got = res["scopes"]
     assert set(got["span_s"]) == spans
     assert got["scan_iters"] > 0
-    share = got["metrics"]["host_stage_share"]
-    assert 0.0 < share < 100.0
+    listed = {m["name"] for m in tiny.metrics(workload, traced=True)}
+    assert ("host_stage_share" in res["metrics"]) == ("host_stage_share" in listed)
+    if "host_stage_share" in listed:
+        assert 0.0 < res["metrics"]["host_stage_share"]["value"] < 100.0
